@@ -70,6 +70,7 @@ def _worker(devices: int, stripes: int, block: int) -> dict:
     from repro.ftx import RepairOptions, StoreConfig, StripeStore, rebalance
     from repro.ftx.events import NodeFailEvent, RackFailEvent, load_trace
     from repro.ftx.failures import replay_trace
+    from repro.launch.mesh import make_mesh
 
     assert len(jax.devices()) == devices
     k, r, p = GEOM
@@ -90,7 +91,7 @@ def _worker(devices: int, stripes: int, block: int) -> dict:
         assert len(store.stripes) == stripes
         return store
 
-    mesh = jax.make_mesh((devices, 1), ("data", "model"))
+    mesh = make_mesh((devices, 1), ("data", "model"))
     out: dict = {"devices": devices, "S": stripes, "B": block,
                  "nodes": NODES, "domains": DOMAINS,
                  "trace_events": len(events)}

@@ -52,6 +52,7 @@ def _worker(devices: int, stripes: int, block: int, stall: float,
     from repro.dist.sharding import with_rules
     from repro.ftx import (RepairOptions, StoreConfig, StripeStore,
                            repair_failed_nodes)
+    from repro.launch.mesh import make_mesh
 
     assert len(jax.devices()) == devices
     k, r, p = GEOM
@@ -73,7 +74,7 @@ def _worker(devices: int, stripes: int, block: int, stall: float,
         sa = build(Path(tmp) / "a")
         sb = build(Path(tmp) / "b")
         node = sa.stripes[0].node_of_block[0]
-        mesh = jax.make_mesh((devices, 1), ("data", "model"))
+        mesh = make_mesh((devices, 1), ("data", "model"))
         with with_rules(mesh):
             rep = repair_failed_nodes(sa, [node], options=RepairOptions(pipeline=True))
         assert rep.devices == devices, (rep.devices, devices)

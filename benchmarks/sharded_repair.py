@@ -35,6 +35,7 @@ def _worker(devices: int, S: int, B: int) -> dict:
     from repro.core.engine import BatchedCodecEngine
     from repro.core.schemes import make_scheme
     from repro.dist.sharding import with_rules
+    from repro.launch.mesh import make_mesh
 
     from benchmarks._util import timed
 
@@ -51,7 +52,7 @@ def _worker(devices: int, S: int, B: int) -> dict:
     base, _ = engine.repair_multi(pattern, avail)
     base = {b: np.asarray(v) for b, v in base.items()}
 
-    mesh = jax.make_mesh((devices, 1), ("data", "model"))
+    mesh = make_mesh((devices, 1), ("data", "model"))
     with with_rules(mesh) as mr:
         def sharded():
             out, _ = engine.repair_multi(pattern, avail, mesh_rules=mr)

@@ -65,6 +65,7 @@ def _worker(devices: int, stripes: int, block: int, policy: str,
     from repro.dist.topology import Topology
     from repro.ftx import (RepairOptions, StoreConfig, StripeStore,
                            repair_failed_nodes)
+    from repro.launch.mesh import make_mesh
 
     assert len(jax.devices()) == devices
     k, r, p = GEOM
@@ -98,7 +99,7 @@ def _worker(devices: int, stripes: int, block: int, policy: str,
             nodes.append(next(
                 n for n in range(num_nodes) if topo.domain_of(n) != d0
                 and any(n in sa.stripes[s].node_of_block for s in sa.stripes)))
-        mesh = jax.make_mesh((devices, 1), ("data", "model"))
+        mesh = make_mesh((devices, 1), ("data", "model"))
         with with_rules(mesh):
             rep = repair_failed_nodes(
                 sa, nodes, options=RepairOptions(pipeline=True,

@@ -28,16 +28,15 @@ import tempfile                                            # noqa: E402
 
 import numpy as np                                         # noqa: E402
 
-import jax                                                 # noqa: E402
-
 from repro.dist.sharding import with_rules                 # noqa: E402
 from repro.dist.topology import POLICIES, Topology         # noqa: E402
 from repro.ftx import (StoreConfig, StripeStore,           # noqa: E402
                        repair_failed_nodes)
+from repro.launch.mesh import make_mesh                    # noqa: E402
 
 S, B, NODES, DOMAINS = 640, 1024, 80, 8
 topo = Topology(num_nodes=NODES, num_domains=DOMAINS, spread_width=2, seed=7)
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 payload = np.random.default_rng(0).integers(0, 256, S * 6 * B,
                                             dtype=np.uint8).tobytes()
 

@@ -20,24 +20,7 @@ from typing import Callable, Optional
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 promotes shard_map out of experimental
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-
 from .sharding import MeshRules, _resolve
-
-
-def _shard_map(body, mesh, in_specs, out_specs):
-    # check_rep=False: pallas_call has no replication rule, and the stripe
-    # launch needs none (coeffs replicate, everything else shards on S).
-    # Newer jax renamed/removed the kwarg; fall back to defaults there.
-    try:
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
 
 
 def stripe_spec(shape, mr: MeshRules) -> P:
@@ -111,33 +94,34 @@ def stripe_span(shape, mr: Optional[MeshRules]) -> int:
 
 
 @functools.lru_cache(maxsize=128)
+def _single(fn: Callable, kwargs_items: tuple) -> Callable:
+    """jit(fn) cache keyed on (fn, static kwargs): the whole single-device
+    launch (padding, packing, kernel, slicing) as one program."""
+    return jax.jit(functools.partial(fn, **dict(kwargs_items)))
+
+
+@functools.lru_cache(maxsize=128)
 def _mapped(fn: Callable, mesh, spec: P, coef_ndim: int,
             kwargs_items: tuple) -> Callable:
     """jit(shard_map(fn)) cache keyed on (fn, mesh, spec, static kwargs).
 
     ``fn`` must be a module-level function (stable identity) taking
     ``(coeffs, batch, **kwargs)``; coeffs replicate, the batch shards on
-    axis 0, and the output inherits the batch's spec.
+    axis 0, and the output inherits the batch's spec. ``check_vma=False``:
+    ``pallas_call`` carries no varying-manual-axes annotation, and the
+    stripe launch needs none (coeffs replicate, everything else shards
+    on S).
     """
-    kwargs = dict(kwargs_items)
-
-    def body(coeffs, batch):
-        return fn(coeffs, batch, **kwargs)
-
-    return jax.jit(_shard_map(
-        body, mesh,
-        in_specs=(P(*([None] * coef_ndim)), spec),
-        out_specs=spec))
+    return jax.jit(jax.shard_map(
+        functools.partial(fn, **dict(kwargs_items)), mesh=mesh,
+        in_specs=(P(*([None] * coef_ndim)), spec), out_specs=spec,
+        check_vma=False))
 
 
 def _matches(batch, sharding: NamedSharding) -> bool:
     """Is ``batch`` already laid out shard-for-shard as ``sharding``?"""
-    if not isinstance(batch, jax.Array):
-        return False
-    try:
-        return batch.sharding.is_equivalent_to(sharding, batch.ndim)
-    except TypeError:                     # older signature
-        return batch.sharding == sharding
+    return (isinstance(batch, jax.Array)
+            and batch.sharding.is_equivalent_to(sharding, batch.ndim))
 
 
 def sharded_launch(fn: Callable, coeffs, batch, mr: Optional[MeshRules],
@@ -158,14 +142,11 @@ def sharded_launch(fn: Callable, coeffs, batch, mr: Optional[MeshRules],
     * anything else (including a single-device ``jax.Array``) — resharded
       by ``device_put`` onto the stripe sharding.
     """
-    import jax.numpy as jnp
-
+    kwargs_items = tuple(sorted(kwargs.items()))
     if stripe_span(batch.shape, mr) <= 1:
-        return fn(coeffs, jnp.asarray(batch, jnp.uint8), **kwargs)
+        return _single(fn, kwargs_items)(coeffs, batch)
     spec = stripe_spec(batch.shape, mr)
     sharding = NamedSharding(mr.mesh, spec)
     if not _matches(batch, sharding):
         batch = jax.device_put(batch, sharding)
-    mapped = _mapped(fn, mr.mesh, spec, coeffs.ndim,
-                     tuple(sorted(kwargs.items())))
-    return mapped(coeffs, batch)
+    return _mapped(fn, mr.mesh, spec, coeffs.ndim, kwargs_items)(coeffs, batch)

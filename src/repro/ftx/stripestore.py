@@ -70,10 +70,11 @@ class StoreConfig:
     r: int = 2
     p: int = 2
     block_size: int = 1 << 20          # bytes per block
-    # Kernel backend: REPRO_BACKEND when set, else the serving-tuned jnp
-    # table path ("gf"/"crs"/"mxu" = Pallas; see kernels.ops.BACKENDS).
+    # Kernel backend: REPRO_BACKEND when set, else the engine's Pallas
+    # default on a TPU and the jnp table oracle "ref" on a CPU host
+    # ("gf"/"crs"/"mxu" = Pallas; see kernels.ops.BACKENDS).
     backend: str = dataclasses.field(
-        default_factory=lambda: _default_backend("ref"))
+        default_factory=lambda: _default_backend(cpu="ref"))
     bandwidth_gbps: float = 1.0        # per-link model for simulated time
     hedge: int = 0                     # extra sources for hedged reads
     seed: int = 0

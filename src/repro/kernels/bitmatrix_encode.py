@@ -1,19 +1,19 @@
-"""Pallas TPU kernels: Cauchy-RS bitmatrix (CRS) encode on packed bit-planes.
+"""Pallas TPU kernels: Cauchy-RS bitmatrix (CRS) products on packed bit-planes.
 
 Two TPU-native formulations of the same GF(2) product
-``out[i] = XOR_{j : bm[i,j]=1} packets[j]`` (see DESIGN.md §3):
+``out[s, i] = XOR_{j : bm[i,j]=1} packets[s, j]`` (see DESIGN.md §3):
 
-* ``bitmatrix_encode`` — VPU path: select-and-XOR accumulation over packet
-  rows. Zero multiplies; the inner loop is one masked XOR per (row, packet).
-* ``mod2_matmul_encode`` — MXU path (beyond-paper optimization): XOR-sums
-  over GF(2) are ordinary sums mod 2, so unpack bytes to 0/1 bit lanes,
-  run a *real* bf16 matmul on the systolic array (counts <= k*8 << 2^24 are
-  exact in f32 accumulation), reduce mod 2 and repack. The whole
-  unpack->dot->mod2->repack chain is fused in one kernel so the 8x-inflated
-  bit tensor never leaves VMEM.
+* ``bitmatrix_encode_batched`` — VPU path: select-and-XOR accumulation over
+  packet rows. Zero multiplies; it is the shared XOR-matmul kernel of
+  ``gf256_matmul`` with 1-bit coefficients, packed 32 to an SMEM word.
+* ``mod2_matmul_encode_batched`` — MXU path: XOR-sums over GF(2) are
+  ordinary sums mod 2, so each bit position of the packed bytes is a 0/1
+  plane and one *real* bf16 matmul per plane on the systolic array
+  (counts <= 8k << 2^24 are exact in f32 accumulation), reduced mod 2 and
+  shifted back into place, gives the product. The planes never leave VMEM.
 
 Inputs use the packed bit-plane layout of ``repro.kernels.ref.packetize``:
-packets (k*8, P) where P = block_bytes / 8.
+packets (S, k*8, P) where P = block_bytes / 8.
 """
 from __future__ import annotations
 
@@ -23,202 +23,89 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .gf256_matmul import xor_matmul
+
 _BITS = 8
+_WORD = 32
 
 
 # --------------------------------------------------------------------------
 # VPU select-and-XOR path
 # --------------------------------------------------------------------------
-def _bitmatrix_kernel(bm_ref, pk_ref, out_ref, *, k8: int):
-    bm = bm_ref[...].astype(jnp.int32)   # (TR, K8)
-    pk = pk_ref[...].astype(jnp.int32)   # (K8, TP)
-    tr, tp = out_ref.shape
-
-    def step(j, acc):
-        row = jax.lax.dynamic_slice(pk, (j, 0), (1, tp))   # (1, TP)
-        sel = jax.lax.dynamic_slice(bm, (0, j), (tr, 1))   # (TR, 1)
-        # sel is {0,1}: multiply == select; XOR-accumulate.
-        return acc ^ (sel * row)
-
-    acc = jax.lax.fori_loop(0, k8, step, jnp.zeros((tr, tp), jnp.int32))
-    out_ref[...] = acc.astype(jnp.uint8)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_r", "tile_p", "interpret"))
-def bitmatrix_encode(bitmatrix: jax.Array, packets: jax.Array, *,
-                     tile_r: int = 8, tile_p: int = 1024,
-                     interpret: bool = False) -> jax.Array:
-    """CRS encode: bitmatrix (R8, K8) {0,1} x packets (K8, P) -> (R8, P)."""
-    r8, k8 = bitmatrix.shape
-    k8b, p = packets.shape
-    if k8 != k8b:
-        raise ValueError(f"shape mismatch {bitmatrix.shape} vs {packets.shape}")
-    tr = min(tile_r, r8)
-    tp = min(tile_p, p)
-    if r8 % tr or p % tp:
-        raise ValueError(f"(R8={r8}, P={p}) must divide tiles ({tr}, {tp})")
-    return pl.pallas_call(
-        functools.partial(_bitmatrix_kernel, k8=k8),
-        grid=(r8 // tr, p // tp),
-        in_specs=[
-            pl.BlockSpec((tr, k8), lambda i, j: (i, 0)),
-            pl.BlockSpec((k8, tp), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((tr, tp), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r8, p), jnp.uint8),
-        interpret=interpret,
-    )(bitmatrix, packets)
-
-
-def _bitmatrix_batched_kernel(bm_ref, pk_ref, out_ref, *, k8: int):
-    """One stripe's (TR, TP) output tile of the (S, R8, P) batched apply.
-
-    The grid's leading axis walks stripes (like ``gf256_matmul_batched``);
-    the bitmatrix block is shared across all of them — one compiled plan's
-    bit expansion, S payloads.
-    """
-    bm = bm_ref[...].astype(jnp.int32)   # (TR, K8)
-    pk = pk_ref[0].astype(jnp.int32)     # block (1, K8, TP) -> (K8, TP)
-    tr, tp = out_ref.shape[1:]
-
-    def step(j, acc):
-        row = jax.lax.dynamic_slice(pk, (j, 0), (1, tp))   # (1, TP)
-        sel = jax.lax.dynamic_slice(bm, (0, j), (tr, 1))   # (TR, 1)
-        return acc ^ (sel * row)
-
-    acc = jax.lax.fori_loop(0, k8, step, jnp.zeros((tr, tp), jnp.int32))
-    out_ref[0] = acc.astype(jnp.uint8)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_r", "tile_p", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def bitmatrix_encode_batched(bitmatrix: jax.Array, packets: jax.Array, *,
-                             tile_r: int = 8, tile_p: int = 1024,
                              interpret: bool = False) -> jax.Array:
     """Batched CRS apply: ``bitmatrix (R8, K8) x packets (S, K8, P) ->
-    (S, R8, P)``.
+    (S, R8, P)``, one launch.
 
-    One Pallas launch covers every stripe: the grid gains a leading stripe
-    axis ``(S, R8/TR, P/TP)`` and the packet/output BlockSpecs index it,
-    while the (small) bitmatrix block is broadcast to all stripes. This is
-    the batched engine's bit-plane workhorse — repair/decode coefficient
-    matrices expanded once per plan apply to a whole stripe batch in one
-    launch (DESIGN.md §11).
+    The bitmatrix is one compiled plan's bit expansion, shared by every
+    stripe; its rows are packed 32 bits to an int32 word so that the widest
+    P8 decode (768 x 768 bits, 72 KiB) fits SMEM. ``P`` must be a
+    ``gf256_matmul.padded_length``.
     """
     r8, k8 = bitmatrix.shape
-    s, k8b, p = packets.shape
-    if k8 != k8b:
+    if packets.shape[1] != k8:
         raise ValueError(f"shape mismatch {bitmatrix.shape} vs {packets.shape}")
-    tr = min(tile_r, r8)
-    tp = min(tile_p, p)
-    if r8 % tr or p % tp:
-        raise ValueError(f"(R8={r8}, P={p}) must divide tiles ({tr}, {tp})")
-    return pl.pallas_call(
-        functools.partial(_bitmatrix_batched_kernel, k8=k8),
-        grid=(s, r8 // tr, p // tp),
-        in_specs=[
-            pl.BlockSpec((tr, k8), lambda si, i, j: (i, 0)),
-            pl.BlockSpec((1, k8, tp), lambda si, i, j: (si, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, tr, tp), lambda si, i, j: (si, i, j)),
-        out_shape=jax.ShapeDtypeStruct((s, r8, p), jnp.uint8),
-        interpret=interpret,
-    )(bitmatrix, packets)
+    w = -(-k8 // _WORD)
+    bm = jnp.pad(bitmatrix.astype(jnp.uint32), ((0, 0), (0, w * _WORD - k8)))
+    shifts = jnp.arange(_WORD, dtype=jnp.uint32)
+    words = jnp.sum(bm.reshape(r8, w, _WORD) << shifts, axis=-1,
+                    dtype=jnp.uint32)
+    words = jax.lax.bitcast_convert_type(words, jnp.int32).reshape(r8 * w)
+    return xor_matmul(words, packets, m=r8, bits=1, interpret=interpret,
+                      coef_at=lambda ref, i, j: ref[i * w + (j >> 5)]
+                      >> (j & 31))
 
 
 # --------------------------------------------------------------------------
 # MXU mod-2 matmul path
 # --------------------------------------------------------------------------
+TILE_P = 512  # packet lanes per grid step
+
+
+def mod2_padded_length(p: int) -> int:
+    """Smallest packet length >= ``p`` that :func:`mod2_matmul_encode_batched`
+    tiles: whole 128-lane rows, and whole ``TILE_P`` steps beyond one."""
+    p = -(-p // 128) * 128
+    return p if p <= TILE_P else -(-p // TILE_P) * TILE_P
+
+
 def _mod2_kernel(bm_ref, pk_ref, out_ref):
+    """One stripe's (R8, TP) output slab: per bit plane b, one bf16 dot of
+    the (R8, K8) bitmatrix with the plane's 0/1 values, reduced mod 2 and
+    shifted back to bit b."""
     bm = bm_ref[...]                       # (R8, K8) bf16 of 0/1
-    pk = pk_ref[...].astype(jnp.int32)     # (K8, TP) packed bytes
-    r8, k8 = bm.shape
-    _, tp = pk.shape
-    # Unpack to bit lanes: (K8, TP, 8) -> (K8, TP*8), values {0,1}.
-    bits = (pk[:, :, None] >> jax.lax.broadcasted_iota(jnp.int32, (1, 1, _BITS), 2)) & 1
-    bits = bits.reshape(k8, tp * _BITS).astype(jnp.bfloat16)
-    # Systolic matmul; f32 accumulation keeps counts (<= k8 < 2^24) exact.
-    counts = jax.lax.dot_general(
-        bm, bits, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    outbits = counts.astype(jnp.int32) & 1                    # (R8, TP*8)
-    outbits = outbits.reshape(r8, tp, _BITS)
-    weights = 1 << jax.lax.broadcasted_iota(jnp.int32, (1, 1, _BITS), 2)
-    out_ref[...] = jnp.sum(outbits * weights, axis=-1).astype(jnp.uint8)
+    pk = pk_ref[0].astype(jnp.int32)       # (K8, TP) packed bytes
+    acc = jnp.zeros(out_ref.shape[1:], jnp.int32)
+    for b in range(_BITS):
+        plane = ((pk >> b) & 1).astype(jnp.float32).astype(jnp.bfloat16)
+        counts = jax.lax.dot_general(
+            bm, plane, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc = acc | ((counts.astype(jnp.int32) & 1) << b)
+    out_ref[0] = acc.astype(jnp.uint8)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_p", "interpret"))
-def mod2_matmul_encode(bitmatrix: jax.Array, packets: jax.Array, *,
-                       tile_p: int = 256, interpret: bool = False) -> jax.Array:
-    """MXU-path CRS encode. bitmatrix (R8, K8) x packets (K8, P) -> (R8, P).
-
-    VMEM per step (defaults, k=128 => K8=1024, TP=256): bits tensor
-    1024 x 2048 bf16 = 4 MB + packets 256 KB + counts R8 x 2048 f32 — fits
-    with double buffering. R8 (<= 72 for the paper's widest r+p) stays whole.
-    """
-    r8, k8 = bitmatrix.shape
-    k8b, p = packets.shape
-    if k8 != k8b:
-        raise ValueError(f"shape mismatch {bitmatrix.shape} vs {packets.shape}")
-    tp = min(tile_p, p)
-    if p % tp:
-        raise ValueError(f"P={p} must divide tile_p={tp}")
-    bm16 = bitmatrix.astype(jnp.bfloat16)
-    return pl.pallas_call(
-        _mod2_kernel,
-        grid=(p // tp,),
-        in_specs=[
-            pl.BlockSpec((r8, k8), lambda j: (0, 0)),
-            pl.BlockSpec((k8, tp), lambda j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((r8, tp), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((r8, p), jnp.uint8),
-        interpret=interpret,
-    )(bm16, packets)
-
-
-def _mod2_batched_kernel(bm_ref, pk_ref, out_ref):
-    """One stripe's (R8, TP) output slab of the (S, R8, P) batched product.
-
-    Same fused unpack->dot->mod2->repack chain as :func:`_mod2_kernel`; the
-    grid's leading axis walks stripes, the bitmatrix rides along whole.
-    """
-    bm = bm_ref[...]                       # (R8, K8) bf16 of 0/1
-    pk = pk_ref[0].astype(jnp.int32)       # block (1, K8, TP) -> (K8, TP)
-    r8, k8 = bm.shape
-    _, tp = pk.shape
-    bits = (pk[:, :, None] >> jax.lax.broadcasted_iota(jnp.int32, (1, 1, _BITS), 2)) & 1
-    bits = bits.reshape(k8, tp * _BITS).astype(jnp.bfloat16)
-    counts = jax.lax.dot_general(
-        bm, bits, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    outbits = counts.astype(jnp.int32) & 1                    # (R8, TP*8)
-    outbits = outbits.reshape(r8, tp, _BITS)
-    weights = 1 << jax.lax.broadcasted_iota(jnp.int32, (1, 1, _BITS), 2)
-    out_ref[0] = jnp.sum(outbits * weights, axis=-1).astype(jnp.uint8)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_p", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def mod2_matmul_encode_batched(bitmatrix: jax.Array, packets: jax.Array, *,
-                               tile_p: int = 256,
                                interpret: bool = False) -> jax.Array:
     """Batched MXU-path apply: ``bitmatrix (R8, K8) x packets (S, K8, P) ->
     (S, R8, P)`` with a ``(S, P/TP)`` grid — one systolic launch per batch.
 
-    VMEM per step matches :func:`mod2_matmul_encode` exactly (the stripe
-    axis adds grid cells, not working-set bytes): for repair-sized plans
-    (R8 <= 8*(r+p) <= 72) the bf16 bits tensor dominates, well inside the
-    ~16 MB/core budget with double buffering.
+    VMEM per step: the (R8, K8) bf16 bitmatrix, the (K8, TP) packet block,
+    one bf16 plane of it, and the (R8, TP) f32 counts and int32 result —
+    about 8 MiB with double buffering at the widest, a P8 decode
+    (R8 = K8 = 768, TP = 512). ``P`` must be a :func:`mod2_padded_length`.
     """
     r8, k8 = bitmatrix.shape
     s, k8b, p = packets.shape
     if k8 != k8b:
         raise ValueError(f"shape mismatch {bitmatrix.shape} vs {packets.shape}")
-    tp = min(tile_p, p)
-    if p % tp:
-        raise ValueError(f"P={p} must divide tile_p={tp}")
-    bm16 = bitmatrix.astype(jnp.bfloat16)
+    if p != mod2_padded_length(p):
+        raise ValueError(f"P={p} is not tiled; pad to mod2_padded_length()")
+    tp = min(TILE_P, p)
     return pl.pallas_call(
-        _mod2_batched_kernel,
+        _mod2_kernel,
         grid=(s, p // tp),
         in_specs=[
             pl.BlockSpec((r8, k8), lambda si, j: (0, 0)),
@@ -227,4 +114,4 @@ def mod2_matmul_encode_batched(bitmatrix: jax.Array, packets: jax.Array, *,
         out_specs=pl.BlockSpec((1, r8, tp), lambda si, j: (si, 0, j)),
         out_shape=jax.ShapeDtypeStruct((s, r8, p), jnp.uint8),
         interpret=interpret,
-    )(bm16, packets)
+    )(bitmatrix.astype(jnp.bfloat16), packets)

@@ -1,17 +1,19 @@
 """Public ops for the erasure-coding kernels.
 
-Dispatch layer: picks the Pallas kernel and falls back to interpreter
-execution on CPU hosts (this container), with shape padding so callers never
-worry about tile divisibility. ``backend``:
+Dispatch layer: picks the Pallas kernel, compiled by Mosaic on a TPU and
+run by the Pallas interpreter on CPU hosts only (``jax.default_backend()
+== "cpu"``), with shape padding so callers never worry about tile
+divisibility. ``backend``:
 
-  "gf"    — gf256_matmul Pallas kernel (bit-serial VPU multiply)
-  "crs"   — bitmatrix_encode Pallas kernel (select-and-XOR on bit-planes)
-  "mxu"   — mod2_matmul_encode Pallas kernel (systolic mod-2 matmul)
+  "gf"    — gf256_matmul_batched (bit-serial VPU multiply)
+  "crs"   — bitmatrix_encode_batched (select-and-XOR on bit-planes)
+  "mxu"   — mod2_matmul_encode_batched (per-bit-plane MXU matmul mod 2)
   "ref"   — pure-jnp table oracle (no Pallas)
 
-Every backend supports every op — encode, repair/decode combines, flat and
-batched. The bit-plane backends ("crs"/"mxu") run general GF matmuls
-through the packed bit-matrix expansion of the byte coefficient matrix
+Every backend supports every op — encode, repair/decode combines — on a
+stripe batch; the flat ops are the batched ones at S=1. The bit-plane
+backends ("crs"/"mxu") run general GF matmuls through the packed
+bit-matrix expansion of the byte coefficient matrix
 (``repro.core.gf.matrix_to_bitmatrix``): callers that hold a compiled plan
 pass its cached expansion via ``bitmatrix=`` so the 8x blow-up is amortized
 over every chunk of a failure pattern (DESIGN.md §11). There is no silent
@@ -23,7 +25,6 @@ and fleet telemetry.
 """
 from __future__ import annotations
 
-import functools
 import os
 
 import jax
@@ -34,9 +35,9 @@ from repro.core.gf import matrix_to_bitmatrix
 from repro.dist.stripes import sharded_launch
 
 from . import ref as ref_lib
-from .bitmatrix_encode import (bitmatrix_encode, bitmatrix_encode_batched,
-                               mod2_matmul_encode, mod2_matmul_encode_batched)
-from .gf256_matmul import gf256_matmul, gf256_matmul_batched
+from .bitmatrix_encode import (bitmatrix_encode_batched,
+                               mod2_matmul_encode_batched, mod2_padded_length)
+from .gf256_matmul import gf256_matmul_batched, padded_length
 
 BACKENDS = ("gf", "crs", "mxu", "ref")
 # Backends whose general matmul runs on packed bit-planes (GF(2) algebra).
@@ -76,13 +77,13 @@ def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _pad_axis(x: jax.Array, axis: int, mult: int) -> tuple[jax.Array, int]:
+def _pad_to(x: jax.Array, axis: int, length: int) -> tuple[jax.Array, int]:
+    """Zero-pad ``axis`` of ``x`` up to ``length``; returns the original size."""
     size = x.shape[axis]
-    pad = (-size) % mult
-    if pad == 0:
+    if size == length:
         return x, size
     widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
+    widths[axis] = (0, length - size)
     return jnp.pad(x, widths), size
 
 
@@ -100,48 +101,23 @@ def _as_bitmatrix(coef, bitmatrix) -> jax.Array:
     return bm
 
 
-def gf_matmul_op(coef, data, *, backend: str = "gf",
-                 interpret: bool | None = None,
-                 bitmatrix=None) -> jax.Array:
-    """GF(2^8) coef (m,k) @ data (k,B) -> (m,B); pads B to the tile size.
+def gf_matmul_op(coef, data, **kwargs) -> jax.Array:
+    """GF(2^8) ``coef (m,k) @ data (k,B) -> (m,B)``: the batched op at S=1.
 
-    All four backends: gf runs the bit-serial Pallas kernel, ref the jnp
-    table oracle, and crs/mxu apply the coefficient matrix's packed
-    bit-matrix on bit-plane packets (``bitmatrix=`` passes a precomputed
-    expansion, e.g. a compiled plan's cached one).
+    Takes every keyword of :func:`gf_matmul_batch_op` (``backend``,
+    ``interpret``, ``force_pallas``, ``bitmatrix``, ...).
     """
-    require_backend(backend)
-    if interpret is None:
-        interpret = _on_cpu()
-    coef = jnp.asarray(coef, jnp.uint8)
     data = jnp.asarray(data, jnp.uint8)
-    if backend == "ref":
-        return ref_lib.gf256_matmul_ref(coef, data)
-    if backend in BIT_BACKENDS:
-        bm = _as_bitmatrix(coef, bitmatrix)
-        return _crs_bitmatrix_apply(bm, data, backend=backend,
-                                    interpret=interpret)
-    tile_b = 512 if not interpret else 128
-    padded, b = _pad_axis(data, 1, tile_b)
-    coef_p, m = _pad_axis(coef, 0, 8)
-    out = gf256_matmul(coef_p, padded, tile_m=8,
-                       tile_b=tile_b, interpret=interpret)
-    return out[:m, :b]
+    return gf_matmul_batch_op(coef, data[None], **kwargs)[0]
 
 
 def _gf_batch_kernel(coef, data, *, backend: str, interpret: bool,
                      force_pallas: bool) -> jax.Array:
     """Single-device body of the batched GF matmul (shard_map-able)."""
-    if backend == "ref":
+    if backend == "ref" or (interpret and not force_pallas):
         return ref_lib.gf256_matmul_batched_ref(coef, data)
-    if interpret and not force_pallas:
-        return ref_lib.gf256_matmul_batched_ref(coef, data)
-    tile_b = 512 if not interpret else 128
-    padded, b = _pad_axis(data, 2, tile_b)
-    coef_p, m = _pad_axis(coef, 0, 8)
-    out = gf256_matmul_batched(coef_p, padded, tile_m=8,
-                               tile_b=tile_b, interpret=interpret)
-    return out[:, :m, :b]
+    padded, b = _pad_to(data, 2, padded_length(data.shape[2]))
+    return gf256_matmul_batched(coef, padded, interpret=interpret)[:, :, :b]
 
 
 def _bit_matmul_batch_kernel(bm, data, *, backend: str, interpret: bool,
@@ -149,31 +125,28 @@ def _bit_matmul_batch_kernel(bm, data, *, backend: str, interpret: bool,
     """Single-device body of the batched bit-plane matmul (shard_map-able).
 
     ``bm`` is the packed (8m, 8t) GF(2) expansion of a byte coefficient
-    matrix, ``data`` the (S, t, B) read stack. Pads B to the packet
-    granule, packetizes per stripe, runs the stripe-grid kernel, unpacks.
-    On CPU hosts the interpreter replays every grid cell, so an
-    interpreted batch runs the *same formulation* as one fused XLA call
-    (the vmapped jnp oracles) — still select-and-XOR for crs and
+    matrix, ``data`` the (S, t, B) read stack. Pads B so the packet length
+    P = B/8 tiles the kernel, packetizes per stripe, runs the stripe-grid
+    kernel, unpacks. On CPU hosts the interpreter replays every grid cell,
+    so an interpreted batch runs the *same formulation* as one fused XLA
+    call (the vmapped jnp oracles) — still select-and-XOR for crs and
     mod-2 matmul for mxu, so the backend identity is preserved;
     ``force_pallas=True`` runs the batched-grid kernel under the
     interpreter anyway (lockstep tests).
     """
-    tile_p = 1024 if backend == "crs" else 256
-    if interpret:
-        tile_p = 64
-    gran = 8 if (interpret and not force_pallas) else 8 * tile_p
-    padded, b = _pad_axis(data, 2, gran)
-    packets = ref_lib.packetize_batched(padded)
+    p = -(-data.shape[2] // 8)
     if interpret and not force_pallas:
+        padded, b = _pad_to(data, 2, 8 * p)
+        packets = ref_lib.packetize_batched(padded)
         fn = (ref_lib.bitmatrix_encode_batched_ref if backend == "crs"
               else ref_lib.mod2_matmul_encode_batched_ref)
-        par = fn(bm, packets)
-    elif backend == "crs":
-        par = bitmatrix_encode_batched(bm, packets, tile_p=tile_p,
-                                       interpret=interpret)
+        return ref_lib.unpacketize_batched(fn(bm, packets))[:, :, :b]
+    if backend == "crs":
+        kernel, p = bitmatrix_encode_batched, padded_length(p)
     else:
-        par = mod2_matmul_encode_batched(bm, packets, tile_p=tile_p,
-                                         interpret=interpret)
+        kernel, p = mod2_matmul_encode_batched, mod2_padded_length(p)
+    padded, b = _pad_to(data, 2, 8 * p)
+    par = kernel(bm, ref_lib.packetize_batched(padded), interpret=interpret)
     return ref_lib.unpacketize_batched(par)[:, :, :b]
 
 
@@ -183,8 +156,8 @@ def gf_matmul_batch_op(coef, data, *, backend: str = "gf",
                        mesh_rules=None, bitmatrix=None) -> jax.Array:
     """Batched GF(2^8) ``coef (m,k) @ data (S,k,B) -> (S,m,B)``.
 
-    One launch for the whole stripe batch; pads B to the tile size and m to
-    the TM granule, exactly like :func:`gf_matmul_op`. All four backends:
+    One launch for the whole stripe batch; pads B to the kernel's tiling
+    and slices the result back. All four backends:
     gf/ref run the byte-table/bit-serial grid, crs/mxu run the stripe-grid
     bit-plane kernels on the coefficient matrix's packed GF(2) expansion
     (``bitmatrix=`` passes a precomputed one — the batched engine hands in
@@ -230,46 +203,13 @@ def gf_matmul_batch_op(coef, data, *, backend: str = "gf",
                           force_pallas=force_pallas)
 
 
-def _crs_bitmatrix_apply(bm, blocks, *, backend: str,
-                         interpret: bool) -> jax.Array:
-    """Bit-plane encode of byte blocks (k, B) by a precomputed bitmatrix."""
-    tile_p = 1024 if backend == "crs" else 256
-    if interpret:
-        tile_p = 64
-    gran = 8 * tile_p
-    padded, b = _pad_axis(blocks, 1, gran)
-    packets = ref_lib.packetize(padded)
-    if backend == "crs":
-        par = bitmatrix_encode(bm, packets, tile_p=tile_p, interpret=interpret)
-    elif backend == "mxu":
-        par = mod2_matmul_encode(bm, packets, tile_p=tile_p, interpret=interpret)
-    elif backend == "ref":
-        par = ref_lib.bitmatrix_encode_ref(bm, packets)
-    else:
-        raise ValueError(f"unknown backend {backend}")
-    return ref_lib.unpacketize(par)[:, :b]
-
-
-def crs_encode_op(coding: np.ndarray, blocks, *, backend: str = "crs",
-                  interpret: bool | None = None) -> jax.Array:
-    """CRS path: byte blocks (k, B) -> parity (m, B) via the bitmatrix of the
-    GF coding matrix. B is padded to a multiple of the packet granularity."""
-    if interpret is None:
-        interpret = _on_cpu()
-    blocks = jnp.asarray(blocks, jnp.uint8)
-    bm = jnp.asarray(matrix_to_bitmatrix(np.asarray(coding, np.uint8)))
-    return _crs_bitmatrix_apply(bm, blocks, backend=backend,
-                                interpret=interpret)
-
-
 def encode_op(coding: np.ndarray, blocks, *, backend: str = "gf",
-              interpret: bool | None = None) -> jax.Array:
-    """Unified stripe-parity computation across all backends."""
-    require_backend(backend)
-    if backend in ("gf", "ref"):
-        return gf_matmul_op(np.asarray(coding, np.uint8), blocks,
-                            backend=backend, interpret=interpret)
-    return crs_encode_op(coding, blocks, backend=backend, interpret=interpret)
+              interpret: bool | None = None,
+              force_pallas: bool = False) -> jax.Array:
+    """Stripe parity ``blocks (k, B) -> (m, B)`` on any backend."""
+    return gf_matmul_op(np.asarray(coding, np.uint8), blocks,
+                        backend=backend, interpret=interpret,
+                        force_pallas=force_pallas)
 
 
 def encode_batch_op(coding: np.ndarray, blocks, *, backend: str = "gf",
@@ -293,15 +233,13 @@ def encode_batch_op(coding: np.ndarray, blocks, *, backend: str = "gf",
                               mesh_rules=mesh_rules, bitmatrix=bitmatrix)
 
 
-def default_backend(fallback: str | None = None) -> str:
-    """``REPRO_BACKEND`` when set (CI backend-matrix legs), else ``fallback``
-    when given (e.g. the store's serving-tuned "ref"), else the MXU path on
-    TPU (the §Perf winner for wide stripes) and gf elsewhere. Uncached so a
-    test can monkeypatch the env var; constructors resolve it once via
+def default_backend(cpu: str = "gf") -> str:
+    """``REPRO_BACKEND`` when set (CI backend-matrix legs), else the MXU
+    Pallas kernel on a TPU, else ``cpu`` (the store passes "ref", the jnp
+    table oracle). No TPU default is a non-Pallas path. Uncached so a test
+    can monkeypatch the env var; constructors resolve it once via
     ``dataclasses.field(default_factory=...)``."""
     env = os.environ.get("REPRO_BACKEND")
     if env:
         return require_backend(env)
-    if fallback is not None:
-        return require_backend(fallback)
-    return "mxu" if jax.default_backend() == "tpu" else "gf"
+    return "mxu" if jax.default_backend() == "tpu" else require_backend(cpu)

@@ -61,17 +61,18 @@ def gf256_matmul_shift_ref(coef: jax.Array, data: jax.Array) -> jax.Array:
 def packetize(blocks: jax.Array) -> jax.Array:
     """(k, B) byte blocks -> (k*8, B//8) packed bit-plane packets.
 
-    Packet (j*8 + i) is bit-plane i of block j, packed little-endian
-    (bit 0 of packed byte t = bit i of source byte 8t).
+    Each block is viewed as 8 segments of P = B/8 bytes. Packet (j*8 + i)
+    is bit-plane i of block j: bit t of its byte p is bit i of byte p of
+    segment t. Both directions reduce over a leading axis of 8 and keep
+    the long byte axis minor, which the TPU compiler lays out in lanes.
     """
     k, B = blocks.shape
     if B % _BITS:
         raise ValueError(f"block bytes {B} must be divisible by 8")
-    x = blocks.astype(jnp.int32)
-    planes = (x[:, None, :] >> jnp.arange(_BITS)[None, :, None]) & 1  # (k, 8, B)
-    grp = planes.reshape(k, _BITS, B // _BITS, _BITS)  # last axis: 8 source bytes
-    weights = (1 << jnp.arange(_BITS)).astype(jnp.int32)
-    packed = jnp.sum(grp * weights[None, None, None, :], axis=-1)
+    x = blocks.reshape(k, 1, _BITS, B // _BITS).astype(jnp.int32)
+    bit = jnp.arange(_BITS)
+    planes = (x >> bit[None, :, None, None]) & 1      # (k, plane, seg, P)
+    packed = jnp.sum(planes << bit[None, None, :, None], axis=2)
     return packed.reshape(k * _BITS, B // _BITS).astype(jnp.uint8)
 
 
@@ -79,12 +80,13 @@ def unpacketize(packets: jax.Array) -> jax.Array:
     """Inverse of :func:`packetize`: (k*8, B//8) -> (k, B)."""
     k8, P = packets.shape
     k = k8 // _BITS
-    x = packets.reshape(k, _BITS, P).astype(jnp.int32)
-    bits = (x[:, :, :, None] >> jnp.arange(_BITS)[None, None, None, :]) & 1
-    planes = bits.reshape(k, _BITS, P * _BITS)  # (k, plane, B)
-    weights = (1 << jnp.arange(_BITS)).astype(jnp.int32)
-    blocks = jnp.sum(planes * weights[None, :, None], axis=1)
-    return blocks.astype(jnp.uint8)
+    x = packets.reshape(k, _BITS, 1, P).astype(jnp.int32)
+    bit = jnp.arange(_BITS)
+    segs = (x >> bit[None, None, :, None]) & 1        # (k, plane, seg, P)
+    segs = jnp.sum(segs << bit[None, :, None, None], axis=1).astype(jnp.uint8)
+    # For a TPU, concatenating the segments compiles about 8x faster than
+    # the equivalent (k, 8, P) -> (k, B) reshape of bytes.
+    return jnp.concatenate([segs[:, t] for t in range(_BITS)], axis=-1)
 
 
 def packetize_batched(blocks: jax.Array) -> jax.Array:

@@ -18,6 +18,8 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.launch.cache import use_compile_cache
+
 
 def serve_blocks(args) -> None:
     from repro.ftx import StoreConfig, StripeStore, read_report
@@ -87,6 +89,7 @@ def main() -> None:
     ap.add_argument("--clients", type=int, default=8,
                     help="front-end reader threads for --blocks")
     args = ap.parse_args()
+    use_compile_cache()
     if args.blocks:
         serve_blocks(args)
     else:

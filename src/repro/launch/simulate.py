@@ -28,6 +28,7 @@ from repro.core.reliability import (HOURS_PER_YEAR, ReliabilityParams,
 from repro.core.schemes import make_scheme
 from repro.dist.topology import POLICIES, Topology
 from repro.ftx.events import to_doc
+from repro.launch.cache import use_compile_cache
 from repro.sim import (SimParams, UnitHierarchy, calibrated, simulate,
                        simulate_oracle)
 from repro.sim.units import COST_MODELS, MODELS
@@ -145,6 +146,7 @@ def main(argv=None) -> int:
     ap.add_argument("--rebalance", action="store_true",
                     help="run one rebalance pass after the --replay trace")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.replay:
         return _replay(args)

@@ -19,6 +19,7 @@ from repro.data.pipeline import DataConfig, make_pipeline
 from repro.dist.sharding import with_rules
 from repro.ftx.checkpoint import CheckpointConfig, CheckpointManager
 from repro.ftx.stripestore import StoreConfig
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.train.optimizer import AdamWConfig, adamw_init
 from repro.train.train_step import TrainConfig, make_train_step
@@ -46,6 +47,7 @@ def main() -> None:
                          "through the CP-LRC repair path")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     api = get_model(args.arch, smoke=args.smoke)
     cfg = api.cfg

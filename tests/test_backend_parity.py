@@ -19,6 +19,7 @@ from repro.core.engine import BatchedCodecEngine
 from repro.core.schemes import make_scheme
 from repro.dist.sharding import with_rules
 from repro.kernels.ops import BACKENDS, effective_backend
+from repro.launch.mesh import make_mesh
 
 multidevice = pytest.mark.skipif(
     len(jax.devices()) < 8,
@@ -31,7 +32,7 @@ PATTERNS = ("single", "double")
 
 
 def _mesh():
-    return jax.make_mesh((8, 1), ("data", "model"))
+    return make_mesh((8, 1), ("data", "model"))
 
 
 def _pattern(scheme, kind):

@@ -17,6 +17,7 @@ import pytest
 from repro.ftx import (CheckpointConfig, CheckpointManager, StoreConfig,
                        StripeStore)
 from repro.ftx.pipeline import EncodePipeline
+from repro.launch.mesh import make_mesh
 
 multidevice = pytest.mark.skipif(
     len(jax.devices()) < 8,
@@ -185,7 +186,7 @@ def test_restore_after_host_loss_parity_sharded(tmp_path):
     from repro.dist.sharding import with_rules
 
     state = _state(seed=6)
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     cm = CheckpointManager(tmp_path, _cfg())
     with with_rules(mesh):
         info = cm.save(2, state)          # sharded encode launches

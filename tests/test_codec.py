@@ -1,7 +1,7 @@
 """Stripe codec: encode / repair / decode roundtrips, property-based."""
 import numpy as np
 import pytest
-from _prop import given, settings, st  # hypothesis, or deterministic fallback
+from hypothesis import given, settings, strategies as st
 
 from repro.core.codec import StripeCodec
 from repro.core.schemes import SCHEMES, make_scheme

@@ -11,10 +11,12 @@ import jax
 import numpy as np
 import pytest
 
-from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
+
 from repro.core.repair import single_repair_plan
 from repro.ftx import (DegradedReadReport, StoreConfig, StripeStore,
                        read_report, repair_failed_nodes)
+from repro.launch.mesh import make_mesh
 from repro.serve.blocks import BlockServer, zipf_requests
 from repro.serve.telemetry import LatencyRecorder
 
@@ -101,7 +103,7 @@ def test_degraded_reads_bit_identical_under_mesh(tmp_path, scheme):
     truth = _healthy(store)
     store.fail_node(store.stripes[0].node_of_block[0])
     store.fail_node(store.stripes[0].node_of_block[1])
-    with with_rules(jax.make_mesh((8, 1), ("data", "model"))):
+    with with_rules(make_mesh((8, 1), ("data", "model"))):
         got = {k: store.read(*k).tobytes() for k in truth}
     assert got == truth
 

@@ -13,6 +13,7 @@ from repro.core.engine import BatchedCodecEngine
 from repro.core.schemes import make_scheme
 from repro.dist.sharding import with_rules
 from repro.dist.stripes import stripe_span, stripe_spec
+from repro.launch.mesh import make_mesh
 
 multidevice = pytest.mark.skipif(
     len(jax.devices()) < 8,
@@ -20,7 +21,7 @@ multidevice = pytest.mark.skipif(
 
 
 def _mesh():
-    return jax.make_mesh((8, 1), ("data", "model"))
+    return make_mesh((8, 1), ("data", "model"))
 
 
 def _stripes(scheme, S, B, seed=0):
@@ -32,7 +33,7 @@ def _stripes(scheme, S, B, seed=0):
 
 # ------------------------------------------------------------- resolution
 def test_stripe_spec_degrades_on_trivial_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with with_rules(mesh) as mr:
         assert stripe_spec((32, 8, 1024), mr) == P("data", None, None)
         assert stripe_span((32, 8, 1024), mr) == 1
@@ -87,19 +88,21 @@ def test_sharded_repair_bit_identical(backend):
 
 @multidevice
 def test_sharded_pallas_kernel_lockstep():
-    """The batched-grid Pallas kernel itself runs under shard_map — the
+    """Each batched-grid Pallas kernel itself runs under shard_map — the
     path real TPUs take (no CPU table fallback) — in lockstep with the
-    table oracle."""
+    table oracle, on a ragged block length."""
     from repro.kernels.ops import gf_matmul_batch_op
 
     rng = np.random.default_rng(1)
     coef = rng.integers(0, 256, (3, 5), dtype=np.uint8)
-    data = rng.integers(0, 256, (16, 5, 256), dtype=np.uint8)
+    data = rng.integers(0, 256, (16, 5, 264), dtype=np.uint8)
     with with_rules(_mesh()) as mr:
         want = np.asarray(gf_matmul_batch_op(coef, data, backend="ref"))
-        got = np.asarray(gf_matmul_batch_op(coef, data, backend="gf",
-                                            force_pallas=True, mesh_rules=mr))
-    assert (want == got).all()
+        for backend in ("gf", "crs", "mxu"):
+            got = np.asarray(gf_matmul_batch_op(
+                coef, data, backend=backend, force_pallas=True,
+                mesh_rules=mr))
+            assert (want == got).all(), backend
 
 
 @multidevice

@@ -1,7 +1,7 @@
 """GF(2^8) field properties + the paper's Appendix Theorem 1."""
 import numpy as np
 import pytest
-from _prop import given, settings, st  # hypothesis, or deterministic fallback
+from hypothesis import given, settings, strategies as st
 
 from repro.core import gf
 from repro.core.cauchy import (

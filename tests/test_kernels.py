@@ -2,13 +2,14 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _prop import given, settings, st  # hypothesis, or deterministic fallback
+from hypothesis import given, settings, strategies as st
 
 from repro.core.gf import gf_matmul, matrix_to_bitmatrix
 from repro.kernels import ref as R
-from repro.kernels.bitmatrix_encode import bitmatrix_encode, mod2_matmul_encode
-from repro.kernels.gf256_matmul import gf256_matmul
-from repro.kernels.ops import crs_encode_op, encode_op, gf_matmul_op
+from repro.kernels.bitmatrix_encode import (bitmatrix_encode_batched,
+                                           mod2_matmul_encode_batched)
+from repro.kernels.gf256_matmul import gf256_matmul_batched
+from repro.kernels.ops import encode_op, gf_matmul_op
 
 SHAPES = [(2, 4, 128), (4, 6, 256), (8, 24, 512), (9, 96, 128), (3, 17, 384)]
 
@@ -18,7 +19,7 @@ def test_gf256_matmul_kernel(m, k, b, rng):
     coef = rng.integers(0, 256, (m, k), dtype=np.uint8)
     data = rng.integers(0, 256, (k, b), dtype=np.uint8)
     want = gf_matmul(coef, data)
-    got = np.asarray(gf_matmul_op(coef, data, backend="gf"))
+    got = np.asarray(gf_matmul_op(coef, data, backend="gf", force_pallas=True))
     assert (got == want).all()
 
 
@@ -39,7 +40,7 @@ def test_bitmatrix_kernels(m, k, b, backend, rng):
     coef = rng.integers(0, 256, (m, k), dtype=np.uint8)
     data = rng.integers(0, 256, (k, b), dtype=np.uint8)
     want = gf_matmul(coef, data)
-    got = np.asarray(encode_op(coef, data, backend=backend))
+    got = np.asarray(encode_op(coef, data, backend=backend, force_pallas=True))
     assert (got == want).all(), backend
 
 
@@ -70,21 +71,41 @@ def test_packetize_roundtrip(k, words, seed):
 
 
 def test_kernel_tile_sweep(rng):
-    """BlockSpec tiling sweep: result invariant to tile choices."""
+    """The batched kernels called directly, across their tilings: blocks of
+    fewer than ROWS lane rows (the whole axis in one block, 5 rows at
+    b = 640) and several grid steps (b = 8192: two ROWS-row steps of the
+    gf kernel, two TILE_P tiles of the mxu kernel)."""
     coef = rng.integers(0, 256, (8, 16), dtype=np.uint8)
-    data = rng.integers(0, 256, (16, 1024), dtype=np.uint8)
-    want = gf_matmul(coef, data)
-    for tm in (1, 2, 4, 8):
-        for tb in (128, 256, 512, 1024):
-            got = np.asarray(gf256_matmul(jnp.asarray(coef), jnp.asarray(data),
-                                          tile_m=tm, tile_b=tb, interpret=True))
-            assert (got == want).all(), (tm, tb)
     bm = jnp.asarray(matrix_to_bitmatrix(coef))
-    pk = R.packetize(jnp.asarray(data))
-    want_pk = R.packetize(jnp.asarray(want))
-    for tr in (8, 16, 32, 64):
-        got = bitmatrix_encode(bm, pk, tile_r=tr, tile_p=64, interpret=True)
-        assert (np.asarray(got) == np.asarray(want_pk)).all(), tr
-    for tp in (32, 64, 128):
-        got = mod2_matmul_encode(bm, pk, tile_p=tp, interpret=True)
-        assert (np.asarray(got) == np.asarray(want_pk)).all(), tp
+    for s, b in [(1, 128), (2, 640), (1, 8192)]:
+        data = rng.integers(0, 256, (s, 16, b), dtype=np.uint8)
+        want = np.stack([gf_matmul(coef, d) for d in data])
+        got = gf256_matmul_batched(jnp.asarray(coef), jnp.asarray(data),
+                                   interpret=True)
+        assert (np.asarray(got) == want).all(), (s, b)
+        pad = max(1024, b) - b
+        padded = jnp.pad(jnp.asarray(data), ((0, 0), (0, 0), (0, pad)))
+        pk = R.packetize_batched(padded)
+        want_pk = R.bitmatrix_encode_batched_ref(bm, pk)
+        for kernel in (bitmatrix_encode_batched, mod2_matmul_encode_batched):
+            got = kernel(bm, pk, interpret=True)
+            assert (np.asarray(got) == np.asarray(want_pk)).all(), (kernel, b)
+
+
+def test_block_rows_fit_vmem(monkeypatch, rng):
+    """Grid steps take ROWS lane rows unless the step would overflow the
+    VMEM budget, as the P8 bit-plane decode (768 x 768 bits) would at 1 MiB
+    blocks; a halved step computes the same bytes."""
+    from repro.kernels import gf256_matmul as G
+
+    assert G.block_rows(24, 4, 1024) == G.ROWS        # P5 GF encode
+    assert G.block_rows(6, 5, 3) == 3                 # short axis, whole
+    assert G.block_rows(768, 768, 1024) == 16         # P8 bit-plane decode
+    coef = rng.integers(0, 256, (5, 6), dtype=np.uint8)
+    data = rng.integers(0, 256, (2, 6, 64 * G.LANES), dtype=np.uint8)
+    monkeypatch.setattr(G, "_VMEM_BUDGET", 8 * G.LANES * (2 * 6 + 6 * 5))
+    assert G.block_rows(6, 5, 64) == 8
+    got = G.gf256_matmul_batched(jnp.asarray(coef), jnp.asarray(data),
+                                 interpret=True)
+    assert (np.asarray(got) == np.stack([gf_matmul(coef, d)
+                                         for d in data])).all()

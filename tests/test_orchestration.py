@@ -17,7 +17,8 @@ import jax
 import numpy as np
 import pytest
 
-from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
+
 from repro.dist.placement import PlacementMap, block_loads
 from repro.dist.schedule import (greedy_assign, optimize_assignment,
                                  schedule_group)
@@ -28,6 +29,7 @@ from repro.ftx import (RepairOptions, StoreConfig, StripeStore, plan_moves,
 from repro.ftx.events import (NodeFailEvent, dump_trace, from_doc,
                               load_trace, sort_events, to_doc)
 from repro.ftx.failures import replay_trace
+from repro.launch.mesh import make_mesh
 
 REPO = Path(__file__).resolve().parent.parent
 TRACE = Path(__file__).resolve().parent / "data" / "correlated_trace.json"
@@ -38,7 +40,7 @@ multidevice = pytest.mark.skipif(
 
 
 def _mesh(shape=(8, 1)):
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def _trace_store(root, *, stripes=40, block=512, num_nodes=24, domains=12,
@@ -292,7 +294,7 @@ def test_replay_cli_deterministic(tmp_path):
 # ------------------------------------------------- rebuild destinations
 @pytest.mark.parametrize("scheme", ["cp-azure", "cp-uniform"])
 @settings(max_examples=3, deadline=None)
-@given(st.sampled_from([0, 3, 7]))
+@given(domain=st.sampled_from([0, 3, 7]))
 def test_topology_destinations_preserve_invariants(domain, scheme):
     """Permanent loss of two nodes of one domain, on a fleet with spare
     copyset capacity (40 nodes / 8 domains / width 3): topology-aware

@@ -16,9 +16,11 @@ import jax
 import numpy as np
 import pytest
 
-from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
+
 from repro.ftx import (FailureInjector, RepairOptions, StoreConfig,
                        StripeStore, repair_failed_nodes)
+from repro.launch.mesh import make_mesh
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -208,7 +210,7 @@ def test_window_alignment_helpers():
 
     assert stripe_axis_span(None) == 1
     assert align_stripe_window(13, None) == 13
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     from repro.dist.sharding import with_rules
     with with_rules(mesh) as mr:
         assert stripe_axis_span(mr) == 1
@@ -220,7 +222,7 @@ def test_window_alignment_rounds_to_device_span():
     from repro.dist.sharding import with_rules
     from repro.dist.stripes import align_stripe_window, stripe_axis_span
 
-    with with_rules(jax.make_mesh((8, 1), ("data", "model"))) as mr:
+    with with_rules(make_mesh((8, 1), ("data", "model"))) as mr:
         assert stripe_axis_span(mr) == 8
         assert align_stripe_window(20, mr) == 16     # keeps 8-way launches
         assert align_stripe_window(8, mr) == 8
@@ -236,7 +238,7 @@ def test_pipelined_sharded_repair_bit_identical(tmp_path):
     sa = _build(tmp_path / "a", stripes=80, window=8)
     sb = _build(tmp_path / "b", stripes=80)
     node = sa.stripes[0].node_of_block[0]
-    with with_rules(jax.make_mesh((8, 1), ("data", "model"))):
+    with with_rules(make_mesh((8, 1), ("data", "model"))):
         rep = repair_failed_nodes(sa, [node], options=RepairOptions(pipeline=True))
     assert rep.pipelined
     assert rep.devices == 8

@@ -14,6 +14,7 @@ from repro.dist.sharding import with_rules
 from repro.dist.stripes import align_stripe_window, stripe_axis_span
 from repro.ftx import (RepairOptions, StoreConfig, StripeStore,
                        repair_failed_nodes)
+from repro.launch.mesh import make_mesh
 
 multidevice = pytest.mark.skipif(
     len(jax.devices()) < 8,
@@ -21,7 +22,7 @@ multidevice = pytest.mark.skipif(
 
 
 def _mesh(shape=(8, 1)):
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def _build(root, *, stripes=80, block_size=512, batch_stripes=8, **kw):

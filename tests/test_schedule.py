@@ -17,7 +17,8 @@ import jax
 import numpy as np
 import pytest
 
-from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
+
 from repro.dist.placement import PlacementMap
 from repro.dist.schedule import chunk_affinity, schedule_chunk
 from repro.dist.sharding import with_rules
@@ -25,6 +26,7 @@ from repro.dist.topology import (POLICIES, Topology, place_stripe,
                                  placement_from_topology)
 from repro.ftx import (RepairOptions, StoreConfig, StripeStore,
                        repair_failed_nodes)
+from repro.launch.mesh import make_mesh
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -34,7 +36,7 @@ multidevice = pytest.mark.skipif(
 
 
 def _mesh(shape=(8, 1)):
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def _build(root, *, stripes=320, block_size=512, num_nodes=40, domains=8,

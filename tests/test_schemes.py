@@ -3,7 +3,7 @@ import itertools
 
 import numpy as np
 import pytest
-from _prop import given, settings, st  # hypothesis, or deterministic fallback
+from hypothesis import given, settings, strategies as st
 
 from repro.core import gf
 from repro.core.schemes import PAPER_PARAMS, SCHEMES, make_scheme

@@ -5,12 +5,13 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import _resolve, opt_state_sharding, with_rules
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture
 def mesh():
     # 1x1 host mesh with the production axis names
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_resolve_divisible(mesh):
@@ -24,7 +25,7 @@ def test_resolve_divisible(mesh):
     reason="needs XLA_FLAGS=--xla_force_host_platform_device_count=8")
 def test_resolve_indivisible_degrades():
     """Real degradation cases on a 2x4 mesh (the multi-device CI leg)."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with with_rules(mesh) as mr:
         # divisible everywhere: both axes assigned
         assert _resolve((8, 8), ("batch", "ff"), mr) == P("data", "model")
@@ -55,7 +56,7 @@ def test_resolve_indivisible_degrades():
 def test_rule_overrides_and_freed_axes():
     """Overrides reroute logical axes; degradation frees axes for later dims
     (the batch=1 long-context kv_seq context-parallel trick)."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with with_rules(mesh, {"kv_seq": ("data",)}) as mr:
         # batch=1 cannot take "data" (1 % 2 != 0); kv_seq picks it up
         spec = _resolve((1, 1024, 4, 64), ("batch", "kv_seq", "kv_heads", None), mr)
@@ -86,7 +87,7 @@ def test_smoke_lowering_compiles(arch, shape):
     driver the 512-device dry-run uses."""
     from repro.launch.dryrun import lower_cell
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     record, lowered, compiled = lower_cell(arch, shape, mesh, smoke=True)
     assert record["cost"].get("flops", 0) > 0
     assert "error" not in record["memory"]
