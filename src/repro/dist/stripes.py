@@ -94,28 +94,25 @@ def stripe_span(shape, mr: Optional[MeshRules]) -> int:
 
 
 @functools.lru_cache(maxsize=128)
-def _single(fn: Callable, kwargs_items: tuple) -> Callable:
-    """jit(fn) cache keyed on (fn, static kwargs): the whole single-device
-    launch (padding, packing, kernel, slicing) as one program."""
-    return jax.jit(functools.partial(fn, **dict(kwargs_items)))
-
-
-@functools.lru_cache(maxsize=128)
-def _mapped(fn: Callable, mesh, spec: P, coef_ndim: int,
-            kwargs_items: tuple) -> Callable:
-    """jit(shard_map(fn)) cache keyed on (fn, mesh, spec, static kwargs).
+def _launcher(fn: Callable, kwargs_items: tuple, mesh=None,
+              spec: Optional[P] = None, coef_ndim: int = 0) -> Callable:
+    """The jitted launch of ``fn``, cached on (fn, static kwargs, mesh,
+    spec): the whole single-device launch (padding, packing, kernel,
+    slicing) as one program without a mesh, else its ``shard_map``.
 
     ``fn`` must be a module-level function (stable identity) taking
-    ``(coeffs, batch, **kwargs)``; coeffs replicate, the batch shards on
-    axis 0, and the output inherits the batch's spec. ``check_vma=False``:
-    ``pallas_call`` carries no varying-manual-axes annotation, and the
-    stripe launch needs none (coeffs replicate, everything else shards
-    on S).
+    ``(coeffs, batch, **kwargs)``. Under the mesh, coeffs replicate, the
+    batch shards on axis 0, and the output inherits the batch's spec.
+    ``check_vma=False``: ``pallas_call`` carries no varying-manual-axes
+    annotation, and the stripe launch needs none (coeffs replicate,
+    everything else shards on S).
     """
+    body = functools.partial(fn, **dict(kwargs_items))
+    if mesh is None:
+        return jax.jit(body)
     return jax.jit(jax.shard_map(
-        functools.partial(fn, **dict(kwargs_items)), mesh=mesh,
-        in_specs=(P(*([None] * coef_ndim)), spec), out_specs=spec,
-        check_vma=False))
+        body, mesh=mesh, in_specs=(P(*([None] * coef_ndim)), spec),
+        out_specs=spec, check_vma=False))
 
 
 def _matches(batch, sharding: NamedSharding) -> bool:
@@ -144,9 +141,10 @@ def sharded_launch(fn: Callable, coeffs, batch, mr: Optional[MeshRules],
     """
     kwargs_items = tuple(sorted(kwargs.items()))
     if stripe_span(batch.shape, mr) <= 1:
-        return _single(fn, kwargs_items)(coeffs, batch)
+        return _launcher(fn, kwargs_items)(coeffs, batch)
     spec = stripe_spec(batch.shape, mr)
     sharding = NamedSharding(mr.mesh, spec)
     if not _matches(batch, sharding):
         batch = jax.device_put(batch, sharding)
-    return _mapped(fn, mr.mesh, spec, coeffs.ndim, kwargs_items)(coeffs, batch)
+    return _launcher(fn, kwargs_items, mr.mesh, spec,
+                     coeffs.ndim)(coeffs, batch)

@@ -59,13 +59,14 @@ def padded_length(n: int) -> int:
 
 def block_rows(k: int, m: int, n_rows: int) -> int:
     """Lane rows per grid step for ``k`` data rows and ``m`` output rows of
-    ``n_rows`` lane rows each: ``ROWS`` (or all of them when fewer), halved
-    while the step's double-buffered uint8 blocks and int32 accumulator
-    exceed the VMEM budget."""
-    rows = min(ROWS, n_rows)
+    ``n_rows`` lane rows each: ``ROWS``, halved while the step's
+    double-buffered uint8 blocks and int32 accumulator exceed the VMEM
+    budget, or all ``n_rows`` when fewer. A short axis that a halved step
+    does not divide is padded up to whole steps by :func:`xor_matmul`."""
+    rows = ROWS
     while rows > 8 and rows * LANES * (2 * k + 6 * m) > _VMEM_BUDGET:
         rows //= 2
-    return rows
+    return min(rows, n_rows)
 
 
 def _xtime(x):
@@ -110,28 +111,34 @@ def xor_matmul(coef_words: jax.Array, data: jax.Array, *, m: int, coef_at,
     ``coef_words`` is the int32 SMEM image of the coefficients and
     ``coef_at(ref, i, j)`` reads coefficient ``(i, j)`` from it; ``bits``
     is 8 for GF(2^8) and 1 for GF(2). ``n`` must be a
-    :func:`padded_length`. Grid ``(S, n / (ROWS * 128))``.
+    :func:`padded_length`. Grid ``(S, steps)`` of :func:`block_rows` lane
+    rows each.
     """
     s, k, n = data.shape
     if n != padded_length(n):
         raise ValueError(f"length {n} is not tiled; pad to padded_length()")
     n_rows = n // LANES
     rows = block_rows(k, m, n_rows)
+    steps = -(-n_rows // rows)
+    x = data.reshape(s, k, n_rows, LANES)
+    if steps * rows != n_rows:  # a short axis under a halved step
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, steps * rows - n_rows), (0, 0)))
     out = pl.pallas_call(
         functools.partial(_xor_matmul_kernel, m=m, k=k, coef_at=coef_at,
                           bits=bits),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(s, n_rows // rows),
+            grid=(s, steps),
             in_specs=[pl.BlockSpec((1, k, rows, LANES),
                                    lambda si, t, c: (si, 0, t, 0))],
             out_specs=pl.BlockSpec((1, m, rows, LANES),
                                    lambda si, t, c: (si, 0, t, 0)),
             scratch_shapes=[pltpu.VMEM((m, rows, LANES), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((s, m, n_rows, LANES), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((s, m, steps * rows, LANES),
+                                       jnp.uint8),
         interpret=interpret,
-    )(coef_words, data.reshape(s, k, n_rows, LANES))
-    return out.reshape(s, m, n)
+    )(coef_words, x)
+    return out[:, :, :n_rows].reshape(s, m, n)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

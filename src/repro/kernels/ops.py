@@ -204,12 +204,10 @@ def gf_matmul_batch_op(coef, data, *, backend: str = "gf",
 
 
 def encode_op(coding: np.ndarray, blocks, *, backend: str = "gf",
-              interpret: bool | None = None,
-              force_pallas: bool = False) -> jax.Array:
+              interpret: bool | None = None) -> jax.Array:
     """Stripe parity ``blocks (k, B) -> (m, B)`` on any backend."""
     return gf_matmul_op(np.asarray(coding, np.uint8), blocks,
-                        backend=backend, interpret=interpret,
-                        force_pallas=force_pallas)
+                        backend=backend, interpret=interpret)
 
 
 def encode_batch_op(coding: np.ndarray, blocks, *, backend: str = "gf",

@@ -62,12 +62,19 @@ def test_property_decodable_iff_rank(name, seed, nfail):
 @pytest.mark.parametrize("name", ["cp-azure", "cp-uniform"])
 @pytest.mark.parametrize("backend", ["gf", "crs", "mxu", "ref"])
 def test_encode_backends_match(name, backend, rng):
+    """The codec's stripes, and the backend's Pallas kernel (interpreted)
+    on the same parity matrix, match the numpy ground truth."""
+    from repro.kernels.ops import gf_matmul_op
+
     s = make_scheme(name, 12, 3, 3)
     codec = StripeCodec(s, backend=backend)
     data = rng.integers(0, 256, (12, 80), dtype=np.uint8)
     stripe = np.asarray(codec.encode(data))
     want = s.encode(data)  # numpy planning-tier ground truth
     assert (stripe == want).all(), backend
+    parity = gf_matmul_op(s.parity_matrix(), data, backend=backend,
+                          force_pallas=backend != "ref")
+    assert (np.asarray(parity) == want[s.k:]).all(), backend
 
 
 def test_decode_all_any_rank_k_subset(rng):
