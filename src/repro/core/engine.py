@@ -19,18 +19,25 @@ Passing :class:`~repro.dist.sharding.MeshRules` (at construction or per
 call) shards the stripe axis over the mesh's data axes — one device-parallel
 launch per call via ``repro.dist.stripes`` — with bit-identical results;
 ``last_span`` reports how many devices the most recent launch spread over.
+
+Every launch runs in three steps, each a program span (``repro.obs``):
+``repro.launch.h2d`` puts a host input on the device and waits for the copy
+(``execute`` splits this step out only under a trace),
+``repro.launch.device`` dispatches the program and waits for it, and
+``repro.launch.d2h`` copies the result back. ``execute`` and ``encode``
+return host arrays.
 """
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Iterable, Mapping, Optional, Union
 
 import jax
 import numpy as np
 
+from repro import obs
 from repro.dist.sharding import MeshRules
-from repro.dist.stripes import stripe_span
+from repro.dist.stripes import stripe_sharding, stripe_span
 from repro.kernels.ops import (default_backend, effective_backend,
                                encode_batch_op, gf_matmul_batch_op,
                                require_backend)
@@ -50,10 +57,6 @@ class BatchedCodecEngine:
     planner: RepairPlanner | None = None
     mesh_rules: MeshRules | None = None
     last_span: int = dataclasses.field(default=1, init=False)
-    # Wall-clock of the most recent execute() launch, device-synchronized
-    # (block_until_ready) so span accounting upstream sees real compute time
-    # rather than async-dispatch time.
-    last_exec_seconds: float = dataclasses.field(default=0.0, init=False)
     # Formulation the most recent launch actually ran (kernels.ops.
     # effective_backend): equals ``backend`` except for the one documented
     # substitution — an interpreted "gf" batch executes the fused table
@@ -90,15 +93,37 @@ class BatchedCodecEngine:
             raise ValueError(f"expected (S, n, B) availability, got {arr.shape}")
         return arr[:, list(reads), :]
 
+    @staticmethod
+    def _to_device(batch, mr: Optional[MeshRules]) -> jax.Array:
+        """The ``repro.launch.h2d`` step: a host batch goes onto the stripe
+        sharding (or the default device); a device array (e.g. pre-sharded
+        by ``repro.dist.placement.assemble_shards``) moves nothing here and
+        records 0 bytes. The copy is waited for only under a trace: a sync
+        between the copy and the dispatch costs about 1.2 ms a launch."""
+        if not isinstance(batch, np.ndarray):
+            with obs.span("repro.launch.h2d", bytes=0):
+                return obs.block_if_tracing(batch)
+        with obs.span("repro.launch.h2d", bytes=batch.nbytes):
+            sharding = (stripe_sharding(batch.shape, mr)
+                        if stripe_span(batch.shape, mr) > 1 else None)
+            return obs.block_if_tracing(jax.device_put(batch, sharding))
+
+    @staticmethod
+    def _to_host(out: jax.Array) -> np.ndarray:
+        """The ``repro.launch.d2h`` step: the result, copied to the host."""
+        with obs.span("repro.launch.d2h", bytes=out.nbytes):
+            return np.asarray(out)
+
     def execute(self, plan: CompiledPlan, stacked: jax.Array | np.ndarray,
-                mesh_rules: Optional[MeshRules] = None) -> jax.Array:
-        """Run a compiled plan on an already-gathered (S, |reads|, B) stack.
+                mesh_rules: Optional[MeshRules] = None) -> np.ndarray:
+        """Run a compiled plan on an already-gathered (S, |reads|, B) stack
+        and return the (S, |targets|, B) result on the host.
 
         The zero-copy entry point for callers that materialize the read
         stack themselves — skips the per-block gather/stack. ``stacked``
         may be a host numpy array (the stripe store's single-shard gather;
-        scattered straight onto the stripe sharding by the launch layer) or
-        a pre-sharded global ``jax.Array`` built per device shard
+        put straight onto the stripe sharding) or a pre-sharded global
+        ``jax.Array`` built per device shard
         (``repro.dist.placement.assemble_shards``), which is consumed with
         zero re-transfer — never bounced through one device.
         """
@@ -116,26 +141,35 @@ class BatchedCodecEngine:
         self.effective_backend = effective_backend(self.backend)
         bitmatrix = (plan.bit_coeffs()
                      if self.backend in ("crs", "mxu") else None)
-        t0 = time.perf_counter()
-        out = gf_matmul_batch_op(plan.coeffs, stacked,
-                                 backend=self.backend, bitmatrix=bitmatrix,
-                                 mesh_rules=mr)
-        jax.block_until_ready(out)
-        self.last_exec_seconds = time.perf_counter() - t0
-        return out
+        if obs.tracing():
+            # Taken apart only under a trace: with none running, the jit
+            # copies a host stack on its own fast path, which an explicit
+            # device_put would cost 0.3 ms or more a launch.
+            stacked = self._to_device(stacked, mr)
+        with obs.span("repro.launch.device", stripes=stacked.shape[0],
+                      reads=len(plan.reads), targets=len(plan.targets),
+                      backend=self.backend):
+            out = jax.block_until_ready(gf_matmul_batch_op(
+                plan.coeffs, stacked, backend=self.backend,
+                bitmatrix=bitmatrix, mesh_rules=mr))
+        return self._to_host(out)
 
     def _execute(self, plan: CompiledPlan, available: Blocks,
-                 mesh_rules: Optional[MeshRules] = None) -> jax.Array:
+                 mesh_rules: Optional[MeshRules] = None) -> np.ndarray:
         return self.execute(plan, self._gather(available, plan.reads),
                             mesh_rules)
 
     # ------------------------------------------------------------- encoding
     def encode(self, data: jax.Array | np.ndarray,
-               mesh_rules: Optional[MeshRules] = None) -> jax.Array:
-        """(S, k, B) data -> (S, n, B) systematic stripes, one launch."""
+               mesh_rules: Optional[MeshRules] = None) -> np.ndarray:
+        """(S, k, B) data -> (S, n, B) systematic stripes on the host, one
+        launch."""
         import jax.numpy as jnp
 
-        data = jnp.asarray(data, jnp.uint8)
+        if isinstance(data, jax.Array):
+            data = jnp.asarray(data, jnp.uint8)
+        else:
+            data = np.ascontiguousarray(data, np.uint8)
         if data.ndim != 3 or data.shape[1] != self.scheme.k:
             raise ValueError(
                 f"expected (S, {self.scheme.k}, B) data, got {data.shape}")
@@ -145,22 +179,28 @@ class BatchedCodecEngine:
         plan = self.planner.encode_plan()
         bitmatrix = (plan.bit_coeffs()
                      if self.backend in ("crs", "mxu") else None)
-        parity = encode_batch_op(plan.coeffs, data, backend=self.backend,
-                                 mesh_rules=mr, bitmatrix=bitmatrix)
-        return jnp.concatenate([data, parity], axis=1)
+        data = self._to_device(data, mr)
+        with obs.span("repro.launch.device", stripes=data.shape[0],
+                      reads=data.shape[1], targets=self.scheme.n,
+                      backend=self.backend):
+            parity = encode_batch_op(plan.coeffs, data, backend=self.backend,
+                                     mesh_rules=mr, bitmatrix=bitmatrix)
+            out = jax.block_until_ready(
+                jnp.concatenate([data, parity], axis=1))
+        return self._to_host(out)
 
     # ------------------------------------------------------------- repair
     def repair_single(self, failed: int, available: Blocks,
                       policy: str = "paper",
                       mesh_rules: Optional[MeshRules] = None
-                      ) -> tuple[jax.Array, CompiledPlan]:
+                      ) -> tuple[np.ndarray, CompiledPlan]:
         """Rebuild one block across S stripes: (S, B) plus the cached plan."""
         plan = self.planner.single_plan(failed, policy)
         return self._execute(plan, available, mesh_rules)[:, 0, :], plan
 
     def repair_multi(self, failed: Iterable[int], available: Blocks,
                      mesh_rules: Optional[MeshRules] = None
-                     ) -> tuple[dict[int, jax.Array], CompiledPlan]:
+                     ) -> tuple[dict[int, np.ndarray], CompiledPlan]:
         """Rebuild a failure pattern across S stripes in one launch.
 
         Returns ``{block -> (S, B)}``; the cascade is pre-flattened by the
@@ -173,7 +213,7 @@ class BatchedCodecEngine:
 
     # ------------------------------------------------------------- decode
     def decode(self, available: Blocks, ids: Iterable[int] | None = None,
-               mesh_rules: Optional[MeshRules] = None) -> jax.Array:
+               mesh_rules: Optional[MeshRules] = None) -> np.ndarray:
         """(S, k, B) data blocks from any rank-k subset of surviving blocks.
 
         ``ids`` names the surviving blocks; it may be omitted for a Mapping

@@ -98,7 +98,8 @@ def _launcher(fn: Callable, kwargs_items: tuple, mesh=None,
               spec: Optional[P] = None, coef_ndim: int = 0) -> Callable:
     """The jitted launch of ``fn``, cached on (fn, static kwargs, mesh,
     spec): the whole single-device launch (padding, packing, kernel,
-    slicing) as one program without a mesh, else its ``shard_map``.
+    slicing) as one program without a mesh, else its ``shard_map``,
+    named ``gf_launch_<backend>``.
 
     ``fn`` must be a module-level function (stable identity) taking
     ``(coeffs, batch, **kwargs)``. Under the mesh, coeffs replicate, the
@@ -107,7 +108,14 @@ def _launcher(fn: Callable, kwargs_items: tuple, mesh=None,
     annotation, and the stripe launch needs none (coeffs replicate,
     everything else shards on S).
     """
-    body = functools.partial(fn, **dict(kwargs_items))
+    kwargs = dict(kwargs_items)
+
+    def body(coeffs, batch):
+        return fn(coeffs, batch, **kwargs)
+
+    # jit names the program after the function: one stable name per
+    # formulation (jit_gf_launch_mxu, ...) for a trace's module line.
+    body.__name__ = body.__qualname__ = f"gf_launch_{kwargs['backend']}"
     if mesh is None:
         return jax.jit(body)
     return jax.jit(jax.shard_map(

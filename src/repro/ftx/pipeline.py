@@ -46,9 +46,13 @@ pattern is genuinely unrecoverable. Results are bit-identical to the
 synchronous path by construction: GF(2^8) decoding is exact, so windowing,
 thread scheduling and re-planning change wall-clock only, never bytes.
 
-Every stage records wall spans; :class:`PipelineResult` aggregates them so
-overlap is *observable*: ``read+compute+write > wall`` is the pipeline
-working, and ``overlap_seconds`` quantifies it.
+Every stage adds its wall time to :class:`PipelineResult`, so overlap is
+*observable*: ``read+compute+write > wall`` is the pipeline working, and
+``overlap_seconds`` quantifies it. Each stage that runs on one thread in one
+piece is also a program span (``repro.obs``): ``repro.repair.prefetch``,
+``.gather_wait``, ``.launch`` and ``.writeback`` (``repro.encode.*`` for the
+encode pipeline), ``repro.pipeline.release`` for freeing a consumed
+window's input and ``repro.pipeline.drain_wait`` for the last write-backs.
 """
 from __future__ import annotations
 
@@ -61,6 +65,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.dist.placement import assemble_shards, plan_gather
 from repro.dist.stripes import align_stripe_window, stripe_axis_span
 
@@ -100,20 +105,34 @@ def run_double_buffered(windows: Sequence, *, produce, consume,
         drain = consume(win, pending)
         if drain is not None:
             drains.append(writer.submit(drain))
-        pending = nxt
-    wait(drains)
+        # Dropping the consumed window's input frees it, which takes
+        # milliseconds for a large host batch; the span names that time.
+        with obs.span("repro.pipeline.release", window=i):
+            pending = nxt
+    with obs.span("repro.pipeline.drain_wait", drains=len(drains)):
+        wait(drains)
     for f in drains:
         f.result()                       # surface writer-thread errors
 
 
-def _record_span(lock: threading.Lock, res: "PipelineResult", stage: str,
-                 index: int, t0: float, t1: float) -> None:
-    """Append a stage span and bump its aggregate, under the result lock
-    (stages land from the coordinator, packer and writer threads)."""
+def _add_seconds(lock: threading.Lock, res: "PipelineResult", stage: str,
+                 seconds: float) -> None:
+    """Bump a stage's aggregate, under the result lock (stages land from
+    the coordinator, packer and writer threads)."""
     with lock:
-        res.spans.append((stage, index, t0, t1))
         setattr(res, f"{stage}_seconds",
-                getattr(res, f"{stage}_seconds") + (t1 - t0))
+                getattr(res, f"{stage}_seconds") + seconds)
+
+
+@contextlib.contextmanager
+def _stage_span(lock: threading.Lock, res: "PipelineResult", stage: str,
+                name: str, **args):
+    """One stage run in one piece: the program span ``name`` with ``args``,
+    whose wall time adds to ``res.<stage>_seconds``."""
+    t0 = time.perf_counter()
+    with obs.span(name, **args):
+        yield
+    _add_seconds(lock, res, stage, time.perf_counter() - t0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,7 +163,7 @@ class _Fetch:
 
 @dataclasses.dataclass
 class PipelineResult:
-    """Aggregate spans + launch accounting for one pipeline run."""
+    """Stage seconds + launch accounting for one pipeline run."""
     windows: int = 0
     launches: int = 0
     devices: int = 1
@@ -154,7 +173,6 @@ class PipelineResult:
     compute_seconds: float = 0.0           # sum of launch (+ host copy) spans
     write_seconds: float = 0.0             # sum of write-back spans
     wall_seconds: float = 0.0
-    spans: list = dataclasses.field(default_factory=list)  # (stage, win, t0, t1)
     # Stripe-scheduler predictions (repro.dist.schedule): shard-local reads
     # under the order the windows actually used vs. the contiguous order,
     # over schedule_total gather reads. Re-planned sub-windows are excluded
@@ -214,8 +232,11 @@ class RepairPipeline:
         self._span_lock = threading.Lock()
 
     # ------------------------------------------------------------- windows
-    def _windows(self, work: Sequence[tuple[list[int], frozenset[int], object]],
-                 res: PipelineResult) -> list[RepairWindow]:
+    def windows(self, work: Sequence[tuple[list[int], frozenset[int], object]],
+                res: PipelineResult) -> list[RepairWindow]:
+        """Split ``[(sids, down, compiled), ...]`` pattern groups into
+        scheduled windows (part of the caller's planning); the scheduler's
+        predictions add to ``res``."""
         from repro.dist.schedule import schedule_group
 
         from .stripestore import launch_step
@@ -257,16 +278,19 @@ class RepairPipeline:
         """
         reads = win.compiled.reads
         shape = (len(win.sids), len(reads), self.store.cfg.block_size)
-        layout, parts = plan_gather(shape, self.mesh_rules, self.placement)
-        t0 = time.perf_counter()
-        futures: list[Future] = []
-        for part in parts:
-            pool = pools[part.slice_.index % len(pools)] if layout \
-                else pools[0]
-            futures += [pool.submit(self._fill, part.buf, i, j, sid, b,
-                                    part.shard)
-                        for i, sid in enumerate(win.sids[part.lo:part.hi])
-                        for j, b in enumerate(reads)]
+        with obs.span("repro.repair.prefetch", window=win.index,
+                      bytes=int(np.prod(shape))):
+            layout, parts = plan_gather(shape, self.mesh_rules,
+                                        self.placement)
+            t0 = time.perf_counter()
+            futures: list[Future] = []
+            for part in parts:
+                pool = pools[part.slice_.index % len(pools)] if layout \
+                    else pools[0]
+                futures += [pool.submit(self._fill, part.buf, i, j, sid, b,
+                                        part.shard)
+                            for i, sid in enumerate(win.sids[part.lo:part.hi])
+                            for j, b in enumerate(reads)]
         return _Fetch(win, shape, layout, [p.buf for p in parts],
                       futures, t0)
 
@@ -274,10 +298,14 @@ class RepairPipeline:
         """Wait out a prefetch. Returns the batch — a host stack for
         degraded windows, or the pre-sharded global array assembled from
         the per-shard buffers — or None when node deaths invalidated it
-        (the window must re-plan). Non-I/O errors raise."""
-        wait(fetch.futures)
-        t1 = time.perf_counter()
-        self._span(res, "read", fetch.window.index, fetch.t_submit, t1)
+        (the window must re-plan). Non-I/O errors raise. The read stage
+        runs from the prefetch's submit to here; the coordinator's own
+        blocked part is the ``repro.repair.gather_wait`` span."""
+        with obs.span("repro.repair.gather_wait", window=fetch.window.index,
+                      bytes=int(np.prod(fetch.shape))):
+            wait(fetch.futures)
+        _add_seconds(self._span_lock, res, "read",
+                     time.perf_counter() - fetch.t_submit)
         io_failed = False
         for f in fetch.futures:
             err = f.exception()
@@ -297,11 +325,11 @@ class RepairPipeline:
     def _launch(self, win: RepairWindow, stacked,
                 res: PipelineResult) -> dict[int, np.ndarray]:
         engine = self.store.engine
-        t0 = time.perf_counter()
-        out = np.asarray(engine.execute(win.compiled, stacked,
-                                        self.mesh_rules))
-        t1 = time.perf_counter()
-        self._span(res, "compute", win.index, t0, t1)
+        with _stage_span(self._span_lock, res, "compute",
+                         "repro.repair.launch", window=win.index,
+                         stripes=len(win.sids)):
+            out = np.asarray(engine.execute(win.compiled, stacked,
+                                            self.mesh_rules))
         res.launches += 1
         res.devices = max(res.devices, engine.last_span)
         res.device_launches += engine.last_span
@@ -309,15 +337,12 @@ class RepairPipeline:
 
     def _writeback(self, win: RepairWindow, rebuilt: dict[int, np.ndarray],
                    res: PipelineResult) -> None:
-        t0 = time.perf_counter()
-        self.store._finish_repair(list(win.sids), win.down, win.compiled.meta,
-                                  rebuilt, self.spare_of, self.dest_of)
-        t1 = time.perf_counter()
-        self._span(res, "write", win.index, t0, t1)
-
-    def _span(self, res: PipelineResult, stage: str, index: int,
-              t0: float, t1: float) -> None:
-        _record_span(self._span_lock, res, stage, index, t0, t1)
+        with _stage_span(self._span_lock, res, "write",
+                         "repro.repair.writeback", window=win.index,
+                         bytes=sum(v.nbytes for v in rebuilt.values())):
+            self.store._finish_repair(list(win.sids), win.down,
+                                      win.compiled.meta, rebuilt,
+                                      self.spare_of, self.dest_of)
 
     # ------------------------------------------------------------- replan
     def _replan(self, pools: list[ThreadPoolExecutor], win: RepairWindow,
@@ -354,17 +379,16 @@ class RepairPipeline:
         raise IOError(f"stripes {pending}: nodes kept failing during re-plan")
 
     # ---------------------------------------------------------------- run
-    def run(self, work: Sequence[tuple[list[int], frozenset[int], object]]
-            ) -> PipelineResult:
-        """Repair ``[(sids, down, compiled), ...]`` pattern groups.
+    def run(self, windows: Sequence[RepairWindow],
+            res: PipelineResult) -> PipelineResult:
+        """Repair the :meth:`windows` of the caller's pattern groups,
+        accounting into ``res``.
 
         The double buffer: wait on window *i*'s prefetch, immediately
         submit window *i+1*'s, then launch *i* and hand its write-back to
         the writer thread — so at steady state reads, compute and writes
         for three consecutive windows run concurrently.
         """
-        res = PipelineResult()
-        windows = self._windows(work, res)
         res.windows = len(windows)
         if not windows:
             return res
@@ -426,10 +450,10 @@ class EncodePipeline:
     ``launch_step`` (byte-budget-capped, mesh-span-aligned), so encode
     launches shard exactly like repair launches.
 
-    Spans land in the same :class:`PipelineResult` vocabulary as repair:
-    ``read_seconds`` is host packing, ``compute_seconds`` encode + device
-    copy-off, ``write_seconds`` the drain, and ``overlap_seconds`` the
-    stall the double buffer hides — the checkpoint benchmark's
+    Stage seconds land in the same :class:`PipelineResult` vocabulary as
+    repair: ``read_seconds`` is host packing, ``compute_seconds`` encode +
+    device copy-off, ``write_seconds`` the drain, and ``overlap_seconds``
+    the stall the double buffer hides — the checkpoint benchmark's
     encode-overlap fraction is ``overlap / busy``.
 
     ``pipelined=False`` runs the identical stages strictly in sequence
@@ -484,10 +508,10 @@ class EncodePipeline:
     def _encode(self, win: EncodeWindow, batch: np.ndarray,
                 res: PipelineResult) -> np.ndarray:
         engine = self.store.engine
-        t0 = time.perf_counter()
-        out = np.asarray(engine.encode(batch, self.mesh_rules))
-        t1 = time.perf_counter()
-        _record_span(self._span_lock, res, "compute", win.index, t0, t1)
+        with _stage_span(self._span_lock, res, "compute",
+                         "repro.encode.launch", window=win.index,
+                         stripes=win.count):
+            out = np.asarray(engine.encode(batch, self.mesh_rules))
         res.launches += 1
         res.devices = max(res.devices, engine.last_span)
         res.device_launches += engine.last_span
@@ -495,12 +519,12 @@ class EncodePipeline:
 
     def _drain(self, stream, win: EncodeWindow, encoded: np.ndarray,
                res: PipelineResult) -> None:
-        t0 = time.perf_counter()
-        stream.write_window(win.first, encoded)
-        if self.drain_stall > 0.0:
-            time.sleep(self.drain_stall)
-        t1 = time.perf_counter()
-        _record_span(self._span_lock, res, "write", win.index, t0, t1)
+        with _stage_span(self._span_lock, res, "write",
+                         "repro.encode.writeback", window=win.index,
+                         bytes=encoded.nbytes):
+            stream.write_window(win.first, encoded)
+            if self.drain_stall > 0.0:
+                time.sleep(self.drain_stall)
         self.hook("drain", win.index)
 
     # ---------------------------------------------------------------- run
@@ -530,9 +554,10 @@ class EncodePipeline:
 
             def consume(win: EncodeWindow, token):
                 fut, t0 = token
-                batch = fut.result()
-                _record_span(self._span_lock, res, "read", win.index,
-                             t0, time.perf_counter())
+                with obs.span("repro.encode.pack_wait", window=win.index):
+                    batch = fut.result()
+                _add_seconds(self._span_lock, res, "read",
+                             time.perf_counter() - t0)
                 encoded = self._encode(win, batch, res)
                 self.hook("encode", win.index)
                 return lambda: self._drain(stream, win, encoded, res)

@@ -26,6 +26,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.codec import StripeCodec
 from repro.core.engine import BatchedCodecEngine
 from repro.core.repair import (MultiRepairPlan, multi_repair_plan,
@@ -318,12 +319,16 @@ class StripeStore:
         ``shard``/``placement`` attribute the read to a gather shard: a read
         whose source node lives outside ``shard`` is *remote* and pays the
         placement's ``remote_multiplier`` on its link time. Reads with no
-        shard (client/degraded paths) are charged as local.
+        shard (client/degraded paths) are charged as local. The file read
+        is the program span ``repro.store.read_block``, on whichever thread
+        reads.
         """
         node = self.stripes[sid].node_of_block[block]
         if self.nodes[node] is NodeState.DOWN:
             raise IOError(f"node {node} is down")
-        data = np.fromfile(self._block_path(sid, block), dtype=np.uint8)
+        with obs.span("repro.store.read_block", sid=sid, block=block,
+                      bytes=rng[1] - rng[0] if rng else self.cfg.block_size):
+            data = np.fromfile(self._block_path(sid, block), dtype=np.uint8)
         lo, hi = rng if rng else (0, len(data))
         local = placement is None or placement.is_local(node, shard)
         dt = ((hi - lo) * 8 / (self.cfg.bandwidth_gbps * 1e9)
@@ -590,31 +595,32 @@ class StripeStore:
         Live blocks read only the range from disk (the §V-C byte-range
         optimization); lost blocks are reconstructed whole — the unit of
         coalescing and caching — and sliced, so N range reads of one hot
-        lost block still cost one decode launch.
+        lost block still cost one decode launch. The request is the program
+        span ``repro.serve.read``.
         """
-        t0 = time.perf_counter()
-        if sid not in self.stripes:
-            raise KeyError(f"unknown stripe {sid}")
-        if not 0 <= block < self.n:
-            raise IndexError(f"block {block} out of range for n={self.n}")
-        hi = self.cfg.block_size if hi is None else hi
-        if not 0 <= lo <= hi <= self.cfg.block_size:
-            raise ValueError(f"bad byte range [{lo}, {hi}) for block size "
-                             f"{self.cfg.block_size}")
-        if block not in self._down_blocks(sid):
-            try:
-                data = self._read_block(sid, block, (lo, hi))
-            except IOError:
-                # The node died between the down-set check and the read:
-                # take the degraded path with a fresh down-set.
+        with obs.span("repro.serve.read", sid=sid, block=block) as span:
+            t0 = time.perf_counter()
+            if sid not in self.stripes:
+                raise KeyError(f"unknown stripe {sid}")
+            if not 0 <= block < self.n:
+                raise IndexError(f"block {block} out of range for n={self.n}")
+            hi = self.cfg.block_size if hi is None else hi
+            if not 0 <= lo <= hi <= self.cfg.block_size:
+                raise ValueError(f"bad byte range [{lo}, {hi}) for block size "
+                                 f"{self.cfg.block_size}")
+            degraded = block in self._down_blocks(sid)
+            if not degraded:
+                try:
+                    data = self._read_block(sid, block, (lo, hi))
+                except IOError:
+                    # The node died between the down-set check and the read:
+                    # take the degraded path with a fresh down-set.
+                    degraded = True
+            if degraded:
                 data = self._read_degraded(sid, block, options)[lo:hi].copy()
-                self._account_read(t0, lo, hi, degraded=True)
-                return data
-            self._account_read(t0, lo, hi, degraded=False)
+            span.set_metadata(bytes=hi - lo, degraded=degraded)
+            self._account_read(t0, lo, hi, degraded=degraded)
             return data
-        data = self._read_degraded(sid, block, options)[lo:hi].copy()
-        self._account_read(t0, lo, hi, degraded=True)
-        return data
 
     def _account_read(self, t0: float, lo: int, hi: int, *,
                       degraded: bool) -> None:
@@ -662,7 +668,8 @@ class StripeStore:
         with self._tele_lock:
             self.telemetry.cache_misses += 1
         if entry is not None and not leader:
-            entry.event.wait()
+            with obs.span("repro.serve.park", sid=sid, block=block):
+                entry.event.wait()
             with self._tele_lock:
                 self.telemetry.coalesced_reads += 1
             if entry.error is not None:
@@ -688,59 +695,63 @@ class StripeStore:
 
     def _decode_block(self, sid: int, block: int, *,
                       cache_self: bool = True) -> np.ndarray:
-        """One serving-path reconstruction: plan, gather, single launch.
+        """One serving-path reconstruction: plan, gather, single launch,
+        the program span ``repro.serve.decode``.
 
         A source node dying between plan selection and gather surfaces as
         an IOError on the read; the loop re-plans against the fresh
         down-set (``serve_replans`` counts these) until a feasible plan's
         sources all survive the gather, or the pattern goes unrecoverable.
         """
-        attempts = 0
-        while True:
-            down = self._down_blocks(sid)
-            if self.read_hook:
-                self.read_hook("plan", sid, block)
-            try:
-                plan = self.engine.planner.serving_plan(block, down)
-            except RuntimeError:
-                raise IOError(f"stripe {sid}: block {block} unrecoverable "
-                              f"({sorted(down)})") from None
-            if self.read_hook:
-                self.read_hook("gather", sid, block)
-            try:
-                stacked = np.stack(
-                    [self._read_block(sid, b) for b in plan.reads])[None]
-            except IOError:
-                attempts += 1
+        with obs.span("repro.serve.decode", sid=sid, block=block) as span:
+            attempts = 0
+            while True:
+                down = self._down_blocks(sid)
+                if self.read_hook:
+                    self.read_hook("plan", sid, block)
+                try:
+                    plan = self.engine.planner.serving_plan(block, down)
+                except RuntimeError:
+                    raise IOError(f"stripe {sid}: block {block} unrecoverable "
+                                  f"({sorted(down)})") from None
+                span.set_metadata(reads=len(plan.reads))
+                if self.read_hook:
+                    self.read_hook("gather", sid, block)
+                try:
+                    stacked = np.stack(
+                        [self._read_block(sid, b) for b in plan.reads])[None]
+                except IOError:
+                    attempts += 1
+                    with self._tele_lock:
+                        self.telemetry.serve_replans += 1
+                    if attempts > self.n:
+                        raise
+                    continue
+                if self.read_hook:
+                    self.read_hook("decode", sid, block)
+                out = np.asarray(self.engine.execute(plan, stacked))
+                meta = plan.meta
+                local = (meta.all_local if isinstance(meta, MultiRepairPlan)
+                         else meta is not None and meta.method != "global")
                 with self._tele_lock:
-                    self.telemetry.serve_replans += 1
-                if attempts > self.n:
-                    raise
-                continue
-            if self.read_hook:
-                self.read_hook("decode", sid, block)
-            out = np.asarray(self.engine.execute(plan, stacked))
-            meta = plan.meta
-            local = (meta.all_local if isinstance(meta, MultiRepairPlan)
-                     else meta is not None and meta.method != "global")
-            with self._tele_lock:
-                self.telemetry.serve_decode_launches += 1
-                if local:
-                    self.telemetry.serve_local_decodes += 1
-                else:
-                    self.telemetry.serve_global_decodes += 1
-            # The multi-plan fallback rebuilds the stripe's whole failure
-            # pattern in the same launch; cache every target so sibling
-            # lost blocks serve for free.
-            result = None
-            for t, b in enumerate(plan.targets):
-                rebuilt = out[0, t, :]
-                if cache_self or b != block:
-                    self._cache_put(sid, b, rebuilt)
-                if b == block:
-                    result = rebuilt
-            assert result is not None, "plan targets must include the block"
-            return result
+                    self.telemetry.serve_decode_launches += 1
+                    if local:
+                        self.telemetry.serve_local_decodes += 1
+                    else:
+                        self.telemetry.serve_global_decodes += 1
+                # The multi-plan fallback rebuilds the stripe's whole failure
+                # pattern in the same launch; cache every target so sibling
+                # lost blocks serve for free.
+                result = None
+                for t, b in enumerate(plan.targets):
+                    rebuilt = out[0, t, :]
+                    if cache_self or b != block:
+                        self._cache_put(sid, b, rebuilt)
+                    if b == block:
+                        result = rebuilt
+                assert result is not None, \
+                    "plan targets must include the block"
+                return result
 
     # ------------------------------------------------------------- repair
     def fail_node(self, node: int) -> None:
@@ -862,7 +873,6 @@ class StripeStore:
         from repro.dist.schedule import schedule_group
         from repro.dist.sharding import current_rules
         from repro.dist.stripes import stripe_axis_span
-        from repro.dist.topology import pick_destinations
 
         o = options if options is not None else RepairOptions()
         batched, mesh_rules = o.batched, o.mesh_rules
@@ -891,38 +901,6 @@ class StripeStore:
                                     else self.cfg.pipeline_window > 0)
         before = self.telemetry.copy()
         t0 = time.perf_counter()
-        affected: dict[frozenset[int], list[int]] = {}
-        for sid in self.stripes:
-            down = self._down_blocks(sid)
-            if down:
-                affected.setdefault(down, []).append(sid)
-        # Topology-aware rebuild destinations: decide, up front and from the
-        # pre-repair placement snapshot, a surviving home for every lost
-        # block (repro.dist.topology.pick_destinations). Applied at
-        # write-back; deterministic in (topology, placements, alive set).
-        dest_of: Optional[dict[tuple[int, int], int]] = None
-        dest_copyset = dest_total = 0
-        if destinations == "topology" and affected:
-            from repro.dist.placement import block_loads
-
-            alive = {n for n, s in self.nodes.items() if s is NodeState.UP}
-            lost = [(sid, b) for down, g_sids in affected.items()
-                    for sid in g_sids for b in down]
-            placements = {sid: list(self.stripes[sid].node_of_block)
-                          for _, g_sids in affected.items() for sid in g_sids}
-            loads = block_loads((s.node_of_block
-                                 for s in self.stripes.values()),
-                                self.num_nodes)
-            dest_of = pick_destinations(
-                self.topology, self.cfg.placement_policy, placements,
-                lost, alive, loads=loads)
-            dest_total = len(dest_of)
-            for (sid, b), node in dest_of.items():
-                live = {self.topology.domain_of(n)
-                        for i, n in enumerate(placements[sid])
-                        if (sid, i) not in dest_of}
-                if self.topology.domain_of(node) in live:
-                    dest_copyset += 1
         launches = 0
         devices = 1
         device_launches = 0
@@ -937,8 +915,50 @@ class StripeStore:
         # a mixed-failure fleet rebuilds everything it can before raising.
         unrecoverable: Optional[IOError] = None
         work: list[tuple[list[int], frozenset[int], object]] = []
-        for down, sids in sorted(affected.items(), key=lambda kv: kv[1][0]):
-            if not batched:
+        pipe = res = None
+        # Planning, up to the first window's prefetch, is one program span.
+        with obs.span("repro.repair.plan") as plan_span:
+            affected: dict[frozenset[int], list[int]] = {}
+            for sid in self.stripes:
+                down = self._down_blocks(sid)
+                if down:
+                    affected.setdefault(down, []).append(sid)
+            groups = sorted(affected.items(), key=lambda kv: kv[1][0])
+            plan_span.set_metadata(
+                stripes=sum(len(sids) for sids in affected.values()),
+                patterns=len(affected))
+            # Topology-aware rebuild destinations: decide, up front and from
+            # the pre-repair placement snapshot, a surviving home for every
+            # lost block (repro.dist.topology.pick_destinations). Applied at
+            # write-back; deterministic in (topology, placements, alive set).
+            dest_of: Optional[dict[tuple[int, int], int]] = None
+            dest_copyset = dest_total = 0
+            if destinations == "topology" and affected:
+                dest_of = self._destinations(affected)
+                dest_total = len(dest_of)
+                dest_copyset = self._destination_copyset(affected, dest_of)
+            for down, sids in groups if batched else ():
+                try:
+                    compiled = self.engine.planner.multi_plan(down)
+                except RuntimeError:
+                    unrecoverable = IOError(
+                        f"stripes {sids} unrecoverable: {sorted(down)}")
+                    break
+                work.append((sids, down, compiled))
+            if use_pipeline and work:
+                from .pipeline import PipelineResult, RepairPipeline
+
+                pipe = RepairPipeline(
+                    self, spare_of=spare_of, dest_of=dest_of,
+                    byte_budget=_BATCH_BYTE_BUDGET,
+                    options=RepairOptions(
+                        mesh_rules=mr, window=window,
+                        pipeline_hook=pipeline_hook, placement=placement,
+                        schedule=schedule))
+                res = PipelineResult()
+                pipe_windows = pipe.windows(work, res)
+        if not batched:
+            for down, sids in groups:
                 for sid in sids:
                     plan = multi_repair_plan(self.scheme, down)
                     if not plan.feasible:
@@ -949,25 +969,8 @@ class StripeStore:
                                         spare_of, dest_of)
                     launches += 1
                     device_launches += 1
-                continue
-            try:
-                compiled = self.engine.planner.multi_plan(down)
-            except RuntimeError:
-                unrecoverable = IOError(
-                    f"stripes {sids} unrecoverable: {sorted(down)}")
-                break
-            work.append((sids, down, compiled))
-        if use_pipeline and work:
-            from .pipeline import RepairPipeline
-
-            res = RepairPipeline(
-                self, spare_of=spare_of, dest_of=dest_of,
-                byte_budget=_BATCH_BYTE_BUDGET,
-                options=RepairOptions(
-                    mesh_rules=mr, window=window,
-                    pipeline_hook=pipeline_hook, placement=placement,
-                    schedule=schedule),
-            ).run(work)
+        if pipe is not None:
+            pipe.run(pipe_windows, res)
             launches += res.launches
             devices = max(devices, res.devices)
             device_launches += res.device_launches
@@ -996,7 +999,8 @@ class StripeStore:
                     sched_total += cs.total_reads
                     span = self._repair_group(list(cs.sids), down,
                                               compiled, spare_of, mr,
-                                              placement, dest_of)
+                                              placement, dest_of,
+                                              index=launches)
                     launches += 1
                     devices = max(devices, span)
                     device_launches += span
@@ -1059,8 +1063,37 @@ class StripeStore:
                 contig_local / sched_total if sched_total else 1.0,
         }
 
+    def _destinations(self, affected: dict[frozenset[int], list[int]]
+                      ) -> dict[tuple[int, int], int]:
+        """A surviving home for every lost block of ``affected``
+        (``repro.dist.topology.pick_destinations``)."""
+        from repro.dist.placement import block_loads
+        from repro.dist.topology import pick_destinations
+
+        alive = {n for n, s in self.nodes.items() if s is NodeState.UP}
+        lost = [(sid, b) for down, g_sids in affected.items()
+                for sid in g_sids for b in down]
+        placements = {sid: list(self.stripes[sid].node_of_block)
+                      for _, g_sids in affected.items() for sid in g_sids}
+        loads = block_loads((s.node_of_block for s in self.stripes.values()),
+                            self.num_nodes)
+        return pick_destinations(self.topology, self.cfg.placement_policy,
+                                 placements, lost, alive, loads=loads)
+
+    def _destination_copyset(self, affected, dest_of) -> int:
+        """How many re-homed blocks landed in a domain their stripe
+        already occupied (before the repair)."""
+        count = 0
+        for (sid, b), node in dest_of.items():
+            live = {self.topology.domain_of(n)
+                    for i, n in enumerate(self.stripes[sid].node_of_block)
+                    if (sid, i) not in dest_of}
+            if self.topology.domain_of(node) in live:
+                count += 1
+        return count
+
     def _gather_group(self, sids: list[int], reads: tuple[int, ...],
-                      mesh_rules, placement):
+                      mesh_rules, placement, index: int = 0):
         """Gather surviving blocks for a stripe group, shard by shard.
 
         Under a sharded mesh each device shard's slice of the batched
@@ -1070,17 +1103,20 @@ class StripeStore:
         (``repro.dist.placement.assemble_shards``). No single-host stack of
         the full batch exists. Degraded/single-device launches keep the
         one-buffer fast path (attributed to gather shard 0). Every read is
-        charged local/remote against ``placement``.
+        charged local/remote against ``placement``. The reads are the
+        coordinator's ``repro.repair.gather_wait`` span of launch ``index``.
         """
         from repro.dist.placement import assemble_shards, plan_gather
 
         shape = (len(sids), len(reads), self.cfg.block_size)
         layout, parts = plan_gather(shape, mesh_rules, placement)
-        for part in parts:
-            for i, sid in enumerate(sids[part.lo:part.hi]):
-                for j, b in enumerate(reads):
-                    part.buf[i, j] = self._read_block(
-                        sid, b, shard=part.shard, placement=placement)
+        with obs.span("repro.repair.gather_wait", window=index,
+                      bytes=int(np.prod(shape))):
+            for part in parts:
+                for i, sid in enumerate(sids[part.lo:part.hi]):
+                    for j, b in enumerate(reads):
+                        part.buf[i, j] = self._read_block(
+                            sid, b, shard=part.shard, placement=placement)
         if layout is None:
             return parts[0].buf
         return assemble_shards(shape, mesh_rules, layout,
@@ -1089,24 +1125,30 @@ class StripeStore:
     def _repair_group(self, sids: list[int], down: frozenset[int],
                       compiled, spare_of: Optional[dict[int, int]],
                       mesh_rules=None, placement=None,
-                      dest_of: Optional[dict[tuple[int, int], int]] = None
-                      ) -> int:
+                      dest_of: Optional[dict[tuple[int, int], int]] = None,
+                      index: int = 0) -> int:
         """Batched repair of stripes sharing one failure pattern: per-shard
         gathers land each device's slice of the (S, |reads|, B) input
         straight on its shard (one host buffer per shard, no full-batch
         stack) and run a single launch (device-parallel under
         ``mesh_rules``; no per-block intermediate copies). Stages run
         strictly serial here — the span accounting makes that visible next
-        to the pipelined path. Returns the device span of the launch."""
+        to the pipelined path; ``index`` numbers the launch in its repair.
+        Returns the device span of the launch."""
         t0 = time.perf_counter()
         stacked = self._gather_group(sids, compiled.reads, mesh_rules,
-                                     placement)
+                                     placement, index)
         t1 = time.perf_counter()
-        out = np.asarray(self.engine.execute(compiled, stacked, mesh_rules))
+        with obs.span("repro.repair.launch", window=index,
+                      stripes=len(sids)):
+            out = np.asarray(self.engine.execute(compiled, stacked,
+                                                 mesh_rules))
         rebuilt = {b: out[:, t, :] for t, b in enumerate(compiled.targets)}
         t2 = time.perf_counter()
-        self._finish_repair(sids, down, compiled.meta, rebuilt, spare_of,
-                            dest_of)
+        with obs.span("repro.repair.writeback", window=index,
+                      bytes=sum(v.nbytes for v in rebuilt.values())):
+            self._finish_repair(sids, down, compiled.meta, rebuilt, spare_of,
+                                dest_of)
         t3 = time.perf_counter()
         with self._tele_lock:
             self.telemetry.read_seconds += t1 - t0

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .gf256_matmul import xor_matmul
+from .gf256_matmul import KERNEL_PREFIX, xor_matmul
 
 _BITS = 8
 _WORD = 32
@@ -114,4 +114,5 @@ def mod2_matmul_encode_batched(bitmatrix: jax.Array, packets: jax.Array, *,
         out_specs=pl.BlockSpec((1, r8, tp), lambda si, j: (si, 0, j)),
         out_shape=jax.ShapeDtypeStruct((s, r8, p), jnp.uint8),
         interpret=interpret,
+        name=KERNEL_PREFIX + "mxu",
     )(bitmatrix.astype(jnp.bfloat16), packets)

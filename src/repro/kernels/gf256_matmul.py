@@ -46,6 +46,10 @@ LANES = 128
 ROWS = 32  # block rows per grid step: one uint8 (32, 128) tile
 # Working-set cap per grid step, under v5e's 16 MiB scoped VMEM.
 _VMEM_BUDGET = 12 << 20
+# Every Pallas kernel of the store is named this prefix plus its
+# formulation ("gf", "crs", "mxu"), so a device trace finds the kernels by
+# name whatever the surrounding jit is called.
+KERNEL_PREFIX = "gf_kernel_"
 
 
 def padded_length(n: int) -> int:
@@ -137,6 +141,7 @@ def xor_matmul(coef_words: jax.Array, data: jax.Array, *, m: int, coef_at,
         out_shape=jax.ShapeDtypeStruct((s, m, steps * rows, LANES),
                                        jnp.uint8),
         interpret=interpret,
+        name=KERNEL_PREFIX + ("gf" if bits == 8 else "crs"),
     )(coef_words, x)
     return out[:, :, :n_rows].reshape(s, m, n)
 

@@ -25,6 +25,7 @@ and fleet telemetry.
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -136,18 +137,20 @@ def _bit_matmul_batch_kernel(bm, data, *, backend: str, interpret: bool,
     """
     p = -(-data.shape[2] // 8)
     if interpret and not force_pallas:
-        padded, b = _pad_to(data, 2, 8 * p)
-        packets = ref_lib.packetize_batched(padded)
-        fn = (ref_lib.bitmatrix_encode_batched_ref if backend == "crs"
-              else ref_lib.mod2_matmul_encode_batched_ref)
-        return ref_lib.unpacketize_batched(fn(bm, packets))[:, :, :b]
-    if backend == "crs":
+        kernel = (ref_lib.bitmatrix_encode_batched_ref if backend == "crs"
+                  else ref_lib.mod2_matmul_encode_batched_ref)
+    elif backend == "crs":
         kernel, p = bitmatrix_encode_batched, padded_length(p)
+        kernel = functools.partial(kernel, interpret=interpret)
     else:
         kernel, p = mod2_matmul_encode_batched, mod2_padded_length(p)
+        kernel = functools.partial(kernel, interpret=interpret)
     padded, b = _pad_to(data, 2, 8 * p)
-    par = kernel(bm, ref_lib.packetize_batched(padded), interpret=interpret)
-    return ref_lib.unpacketize_batched(par)[:, :, :b]
+    with jax.named_scope("pack"):
+        packets = ref_lib.packetize_batched(padded)
+    par = kernel(bm, packets)
+    with jax.named_scope("unpack"):
+        return ref_lib.unpacketize_batched(par)[:, :, :b]
 
 
 def gf_matmul_batch_op(coef, data, *, backend: str = "gf",

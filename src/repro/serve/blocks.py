@@ -1,11 +1,10 @@
 """Degraded-read front end: multi-client block serving over a stripe store.
 
 A thin serving layer over ``StripeStore.read``/``read_range`` (which owns
-the reconstruction, coalescing and caching — DESIGN.md §10): this module
-adds the *client* side — a thread pool standing in for concurrent readers,
-per-request wall-latency recording into a shared
-:class:`~repro.serve.telemetry.LatencyRecorder`, and the Zipfian request
-generator the tail-latency experiments drive it with. The point of the
+the reconstruction, coalescing and caching — DESIGN.md §10, and records
+every request's latency in ``read_latency``): this module adds the *client*
+side — a thread pool standing in for concurrent readers, and the Zipfian
+request generator the tail-latency experiments drive it with. The point of the
 split: N front-end clients hammering one lost block must collapse onto one
 decode launch *inside* the store, so any number of front ends stay correct
 by construction.
@@ -18,8 +17,6 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from .telemetry import LatencyRecorder
-
 
 class BlockServer:
     """Concurrent block-read front end over one stripe store.
@@ -27,25 +24,20 @@ class BlockServer:
     ``read`` serves a single request synchronously; ``run`` replays a
     request stream through ``clients`` worker threads — the multi-client
     load shape of a production object store, where many readers race onto
-    the same hot lost block. Front-end latency (queueing + store time)
-    lands in ``latency``; the store's own counters stay the source of truth
-    for coalescing/cache behavior (``repro.ftx.read_report``).
+    the same hot lost block. The store's counters and its
+    ``read_latency`` are the source of truth for latency and for
+    coalescing/cache behavior (``repro.ftx.read_report``).
     """
 
-    def __init__(self, store, clients: int = 8,
-                 latency: Optional[LatencyRecorder] = None):
+    def __init__(self, store, clients: int = 8):
         if clients < 1:
             raise ValueError("need at least one client thread")
         self.store = store
         self.clients = clients
-        self.latency = latency if latency is not None else LatencyRecorder()
 
     def read(self, sid: int, block: int, lo: int = 0,
              hi: Optional[int] = None) -> np.ndarray:
-        t0 = time.perf_counter()
-        data = self.store.read_range(sid, block, lo, hi)
-        self.latency.record(time.perf_counter() - t0, int(data.size))
-        return data
+        return self.store.read_range(sid, block, lo, hi)
 
     def run(self, requests: Sequence[tuple],
             timed: bool = False) -> list:

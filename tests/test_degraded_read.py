@@ -461,9 +461,10 @@ def test_block_server_preserves_order_and_latency(tmp_path):
     store.fail_node(store.stripes[0].node_of_block[0])
     requests = zipf_requests(store, 64, seed=3)
     server = BlockServer(store, clients=4)
+    before = store.read_latency.snapshot()["count"]
     out = server.run(requests)
     assert [d.tobytes() for d in out] == [truth[k] for k in requests]
-    assert server.latency.snapshot()["count"] == len(requests)
+    assert store.read_latency.snapshot()["count"] - before == len(requests)
     timed = server.run(requests[:8], timed=True)
     assert all(dt >= 0.0 for _, dt in timed)
     assert server.report().degraded_reads >= 0

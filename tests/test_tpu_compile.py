@@ -5,9 +5,10 @@ Each case lowers the single-device launch body a TPU runs
 bit-plane packing, the Pallas kernel, slicing) for one real plan of the
 paper's P5 (24,2,2) and P8 (96,5,4) geometries at 1 MiB blocks, compiles it
 with the TPU compiler for one chip of a ``v5e:2x2`` topology, and checks
-that a Mosaic kernel is in the program. Nothing runs: these compiles say
-nothing about results or times, only that Mosaic accepts the kernels' tiles
-and that each grid step fits the chip's scoped VMEM and SMEM.
+that a Mosaic kernel is in the program, under its stable name. Nothing
+runs: these compiles say nothing about results or times, only that Mosaic
+accepts the kernels' tiles and that each grid step fits the chip's scoped
+VMEM and SMEM.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and the test workers all
@@ -23,6 +24,7 @@ import pytest
 from repro.core.planner import RepairPlanner
 from repro.core.schemes import PAPER_PARAMS, make_scheme
 from repro.kernels import ops
+from repro.kernels.gf256_matmul import KERNEL_PREFIX
 
 BLOCK = 1 << 20   # the StoreConfig default block size
 STRIPES = 4       # the stripe grid axis adds grid cells, not kernel code
@@ -78,5 +80,7 @@ def test_kernel_compiles_for_v5e(geometry, backend, plan, one_chip):
                                   jnp.uint8, sharding=one_chip)
     compiled = jax.jit(fn).lower(coef_s, data_s).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the kernel's stable name, which a device trace finds it by
+    assert f"%{KERNEL_PREFIX}{backend}." in compiled.as_text()
     out = jax.eval_shape(fn, coef_s, data_s)
     assert out.shape == (STRIPES, len(compiled_plan.targets), BLOCK)
