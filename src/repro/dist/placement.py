@@ -24,6 +24,7 @@ stripes in global stripe order.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -189,11 +190,12 @@ class GatherShard:
     lo: int
     hi: int
     shard: int                             # reader (host) shard for accounting
-    buf: np.ndarray                        # (hi - lo, ...) preallocated
+    buf: np.ndarray                        # (hi - lo, ...) to fill
     slice_: Optional[ShardSlice] = None    # None on the degraded path
 
 
-def plan_gather(shape: Sequence[int], mr: Optional[MeshRules], placement
+def plan_gather(shape: Sequence[int], mr: Optional[MeshRules], placement,
+                out: Optional[np.ndarray] = None
                 ) -> tuple[Optional[list[ShardSlice]], list[GatherShard]]:
     """Shared gather geometry for the stripe store and the repair pipeline.
 
@@ -203,29 +205,42 @@ def plan_gather(shape: Sequence[int], mr: Optional[MeshRules], placement
         placement: the active :class:`PlacementMap` (attributes each
             shard's reads), or ``None`` to attribute device shard *i* to
             host shard *i* directly.
+        out: a flat ``uint8`` buffer of at least ``prod(shape)`` bytes to
+            carve the part buffers from (consecutive views, in part order),
+            or ``None`` to allocate them.
 
     Returns:
         ``(layout, parts)``: the :func:`shard_layout` result plus one
-        :class:`GatherShard` per buffer — preallocated ``uint8`` buffers
-        with their stripe ranges and reader-shard attribution. A degraded
-        batch (``layout is None``) gets one full-shape buffer attributed
-        to shard 0 — the single-host gather, charged consistently on both
-        the synchronous and pipelined paths. Sharded batches map device
-        shard *i* onto the placement's host shards contiguously
+        :class:`GatherShard` per buffer — ``uint8`` buffers with their
+        stripe ranges and reader-shard attribution. A degraded batch
+        (``layout is None``) gets one full-shape buffer attributed to shard
+        0 — the single-host gather, charged consistently on both the
+        synchronous and pipelined paths. Sharded batches map device shard
+        *i* onto the placement's host shards contiguously
         (``PlacementMap.reader_shard``), the same stripe->device order the
         layout itself uses.
     """
     shape = tuple(shape)
+    offset = 0
+
+    def buffer(part_shape: tuple[int, ...]) -> np.ndarray:
+        nonlocal offset
+        if out is None:
+            return np.empty(part_shape, np.uint8)
+        size = math.prod(part_shape)
+        view = out[offset:offset + size].reshape(part_shape)
+        offset += size
+        return view
+
     layout = shard_layout(shape, mr)
     if layout is None:
-        return None, [GatherShard(0, shape[0], 0,
-                                  np.empty(shape, np.uint8))]
+        return None, [GatherShard(0, shape[0], 0, buffer(shape))]
     span = len(layout)
     parts = [GatherShard(
         sl.lo, sl.hi,
         placement.reader_shard(sl.index, span) if placement is not None
         else sl.index,
-        np.empty((sl.size,) + shape[1:], np.uint8), sl) for sl in layout]
+        buffer((sl.size,) + shape[1:]), sl) for sl in layout]
     return layout, parts
 
 

@@ -26,7 +26,11 @@ This module overlaps the three stages with a classic double buffer over
   ``PlacementMap`` charging cross-shard reads at the configured remote
   multiplier — while window *i* runs through
   ``BatchedCodecEngine.execute`` (zero re-transfer on the pre-sharded
-  batch);
+  batch). Each block is read once, by ``StripeStore._read_block_into``,
+  straight into its place in the window's buffer, and that buffer is one of
+  the two the store keeps across windows and repairs: the double buffer
+  holds at most two windows' inputs, and a window's slot goes back once its
+  launch has returned;
 * write-back of window *i*'s rebuilt blocks happens on a dedicated writer
   thread, overlapped with the launch of window *i+1*.
 
@@ -77,7 +81,8 @@ PipelineHook = Callable[[str, int], None]
 
 
 def run_double_buffered(windows: Sequence, *, produce, consume,
-                        writer: ThreadPoolExecutor) -> None:
+                        writer: ThreadPoolExecutor,
+                        release: Optional[Callable] = None) -> None:
     """The double-buffer loop shared by every windowed pipeline.
 
     Repair runs it forward (read → decode → write-back) and checkpoint
@@ -91,7 +96,9 @@ def run_double_buffered(windows: Sequence, *, produce, consume,
       entirely inline — e.g. a repair re-plan) or a zero-argument drain
       callable;
     * the drain callable runs on the dedicated ``writer`` thread,
-      overlapped with the next window's consume.
+      overlapped with the next window's consume;
+    * ``release(token)``, when given, runs once the window is consumed,
+      where the loop drops the window's input.
 
     Window *i+1*'s production is always submitted before window *i* is
     consumed, so at steady state three consecutive windows are in flight:
@@ -105,9 +112,12 @@ def run_double_buffered(windows: Sequence, *, produce, consume,
         drain = consume(win, pending)
         if drain is not None:
             drains.append(writer.submit(drain))
-        # Dropping the consumed window's input frees it, which takes
-        # milliseconds for a large host batch; the span names that time.
+        # Dropping the consumed window's input frees what is not given
+        # back, which takes milliseconds for a large host batch; the span
+        # names that time.
         with obs.span("repro.pipeline.release", window=i):
+            if release is not None:
+                release(pending)
             pending = nxt
     with obs.span("repro.pipeline.drain_wait", drains=len(drains)):
         wait(drains)
@@ -144,14 +154,15 @@ class RepairWindow:
     compiled: object                       # CompiledPlan
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class _Fetch:
     """An in-flight window prefetch: futures filling per-shard buffers.
 
     ``layout`` is the window's device-shard geometry (None = degraded /
     single device, one buffer). With a layout, ``bufs[i]`` is shard *i*'s
     slice of the ``(S, |reads|, B)`` batch, filled only by that shard's
-    reader pool.
+    reader pool. The buffers are views of the store's gather ``slot``
+    (None: a fresh buffer of this window's own).
     """
     window: RepairWindow
     shape: tuple[int, int, int]
@@ -159,6 +170,7 @@ class _Fetch:
     bufs: list[np.ndarray]
     futures: list[Future]
     t_submit: float
+    slot: Optional[int]
 
 
 @dataclasses.dataclass
@@ -230,6 +242,8 @@ class RepairPipeline:
         self.byte_budget = byte_budget
         self.hook = o.pipeline_hook or (lambda stage, index: None)
         self._span_lock = threading.Lock()
+        # Prefetches holding a store gather slot, until _release.
+        self._held: list[_Fetch] = []
 
     # ------------------------------------------------------------- windows
     def windows(self, work: Sequence[tuple[list[int], frozenset[int], object]],
@@ -263,8 +277,8 @@ class RepairPipeline:
     # ------------------------------------------------------------- stages
     def _fill(self, buf: np.ndarray, i: int, j: int, sid: int, b: int,
               shard: int) -> None:
-        buf[i, j] = self.store._read_block(sid, b, shard=shard,
-                                           placement=self.placement)
+        self.store._read_block_into(sid, b, buf[i, j], shard=shard,
+                                    placement=self.placement)
 
     def _prefetch(self, pools: list[ThreadPoolExecutor], win: RepairWindow
                   ) -> _Fetch:
@@ -278,10 +292,13 @@ class RepairPipeline:
         """
         reads = win.compiled.reads
         shape = (len(win.sids), len(reads), self.store.cfg.block_size)
+        nbytes = int(np.prod(shape))
         with obs.span("repro.repair.prefetch", window=win.index,
-                      bytes=int(np.prod(shape))):
+                      bytes=nbytes) as span:
+            flat, slot, reused = self.store._take_gather_slot(nbytes)
+            span.set_metadata(reused=int(reused))
             layout, parts = plan_gather(shape, self.mesh_rules,
-                                        self.placement)
+                                        self.placement, out=flat)
             t0 = time.perf_counter()
             futures: list[Future] = []
             for part in parts:
@@ -291,8 +308,18 @@ class RepairPipeline:
                                         part.shard)
                             for i, sid in enumerate(win.sids[part.lo:part.hi])
                             for j, b in enumerate(reads)]
-        return _Fetch(win, shape, layout, [p.buf for p in parts],
-                      futures, t0)
+        fetch = _Fetch(win, shape, layout, [p.buf for p in parts],
+                       futures, t0, slot)
+        if slot is not None:
+            self._held.append(fetch)
+        return fetch
+
+    def _release(self, fetch: _Fetch) -> None:
+        """Give a consumed window's gather slot back to the store: its
+        launch has returned (or it re-planned), so nothing reads it."""
+        if fetch.slot is not None:
+            self._held.remove(fetch)
+            self.store._give_gather_slot(fetch.slot)
 
     def _collect(self, fetch: _Fetch, res: PipelineResult):
         """Wait out a prefetch. Returns the batch — a host stack for
@@ -370,11 +397,14 @@ class RepairPipeline:
                     raise IOError(f"stripes {sids} unrecoverable: "
                                   f"{sorted(down)}") from None
                 sub = RepairWindow(win.index, tuple(sids), down, compiled)
-                stacked = self._collect(self._prefetch(pools, sub), res)
+                fetch = self._prefetch(pools, sub)
+                stacked = self._collect(fetch, res)
                 if stacked is None:          # yet another failure; go again
+                    self._release(fetch)
                     retry.extend(sids)
                     continue
                 self._writeback(sub, self._launch(sub, stacked, res), res)
+                self._release(fetch)
             pending = retry
         raise IOError(f"stripes {pending}: nodes kept failing during re-plan")
 
@@ -397,6 +427,13 @@ class RepairPipeline:
         # disks); a single pool when the mesh degrades to one device.
         num_pools = max(1, stripe_axis_span(self.mesh_rules))
         with contextlib.ExitStack() as stack:
+            @stack.callback
+            def give_back_slots():
+                # Runs last, once the reader pools have shut down: a window
+                # that raised still holds its slot, and no read lands now.
+                for fetch in self._held[:]:
+                    self._release(fetch)
+
             readers = [stack.enter_context(ThreadPoolExecutor(
                 self.threads, thread_name_prefix=f"repair-read-s{s}"))
                 for s in range(num_pools)]
@@ -419,7 +456,7 @@ class RepairPipeline:
                 return lambda: self._writeback(win, rebuilt, res)
 
             run_double_buffered(windows, produce=produce, consume=consume,
-                                writer=writer)
+                                writer=writer, release=self._release)
         res.wall_seconds = time.perf_counter() - t_run
         return res
 

@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import json
+import os
 import threading
 import time
 from collections import OrderedDict
@@ -164,6 +165,11 @@ class Telemetry:
     local_reads: int = 0
     remote_reads: int = 0
     gather_bytes_per_shard: dict = dataclasses.field(default_factory=dict)
+    # Repair-pipeline windows whose gather landed in one of the store's two
+    # kept window buffers as it was, vs. windows that allocated one (a slot
+    # made or grown, or a fresh buffer when no slot was free).
+    gather_buffer_reuses: int = 0
+    gather_buffer_allocs: int = 0
     # Rebuild-destination accounting: blocks whose repair write-back landed
     # on a topology-chosen surviving node instead of the failed block's
     # original address (repro.dist.topology.pick_destinations).
@@ -198,6 +204,7 @@ class Telemetry:
         self.read_seconds = self.compute_seconds = self.write_seconds = 0.0
         self.local_reads = self.remote_reads = 0
         self.gather_bytes_per_shard = {}
+        self.gather_buffer_reuses = self.gather_buffer_allocs = 0
         self.blocks_relocated = 0
         self.direct_reads = self.degraded_reads = self.coalesced_reads = 0
         self.serve_decode_launches = 0
@@ -282,6 +289,12 @@ class StripeStore:
         # telemetry concurrently with the coordinator; counters stay exact
         # under this lock.
         self._tele_lock = threading.Lock()
+        # The repair pipeline's two window gather buffers, kept across
+        # windows and repair_all calls (the double buffer holds at most two
+        # windows' inputs at once); flat uint8, empty until first use, and
+        # handed out under _tele_lock through _take_gather_slot.
+        self._gather_slots = [np.empty(0, np.uint8), np.empty(0, np.uint8)]
+        self._free_slots = [1, 0]
         self.stripes: dict[int, Stripe] = {}
         self.objects: dict[str, ObjectMeta] = {}
         self.telemetry = Telemetry()
@@ -330,8 +343,43 @@ class StripeStore:
                       bytes=rng[1] - rng[0] if rng else self.cfg.block_size):
             data = np.fromfile(self._block_path(sid, block), dtype=np.uint8)
         lo, hi = rng if rng else (0, len(data))
+        self._charge_read(node, hi - lo, shard, placement)
+        return data[lo:hi]
+
+    def _read_block_into(self, sid: int, block: int, out: np.ndarray, *,
+                         shard: Optional[int] = None,
+                         placement=None) -> None:
+        """Read one whole block straight into ``out``, a contiguous ``(B,)``
+        uint8 view (a repair window's gather slot): ``readinto`` on an
+        unbuffered file until ``B`` bytes have arrived, with no array of its
+        own. Liveness, the span, the link model and the counters are
+        :meth:`_read_block`'s; a block file of any other size than ``B``
+        raises ``ValueError``."""
+        node = self.stripes[sid].node_of_block[block]
+        if self.nodes[node] is NodeState.DOWN:
+            raise IOError(f"node {node} is down")
+        view = memoryview(out)
+        with obs.span("repro.store.read_block", sid=sid, block=block,
+                      bytes=out.nbytes):
+            with open(self._block_path(sid, block), "rb", buffering=0) as f:
+                size = os.fstat(f.fileno()).st_size
+                got = 0
+                while got < out.nbytes:
+                    n = f.readinto(view[got:])
+                    if not n:
+                        break
+                    got += n
+        if size != out.nbytes or got != out.nbytes:
+            raise ValueError(f"block s{sid}_b{block} holds {size} bytes, "
+                             f"not {out.nbytes}")
+        self._charge_read(node, out.nbytes, shard, placement)
+
+    def _charge_read(self, node: int, nbytes: int, shard: Optional[int],
+                     placement) -> None:
+        """Charge one read of ``nbytes`` from ``node`` to the link model
+        (slept for ``io_stall_scale`` of it) and the read counters."""
         local = placement is None or placement.is_local(node, shard)
-        dt = ((hi - lo) * 8 / (self.cfg.bandwidth_gbps * 1e9)
+        dt = (nbytes * 8 / (self.cfg.bandwidth_gbps * 1e9)
               + self.latency_ms[node] / 1e3)
         if not local:
             dt *= placement.remote_multiplier
@@ -342,7 +390,7 @@ class StripeStore:
             time.sleep(self.cfg.io_stall_scale * dt)
         with self._tele_lock:
             self.telemetry.blocks_read += 1
-            self.telemetry.bytes_read += hi - lo
+            self.telemetry.bytes_read += nbytes
             self.telemetry.sim_seconds += dt
             if local:
                 self.telemetry.local_reads += 1
@@ -350,8 +398,40 @@ class StripeStore:
                 self.telemetry.remote_reads += 1
             if shard is not None:
                 gbs = self.telemetry.gather_bytes_per_shard
-                gbs[shard] = gbs.get(shard, 0) + (hi - lo)
-        return data[lo:hi]
+                gbs[shard] = gbs.get(shard, 0) + nbytes
+
+    def _take_gather_slot(self, nbytes: int
+                          ) -> tuple[np.ndarray, Optional[int], bool]:
+        """A flat uint8 buffer of ``nbytes`` for one repair window's gather:
+        ``(buf, slot, reused)``. ``slot`` is the kept buffer it is a view
+        of, to give back with :meth:`_give_gather_slot` once no launch can
+        read it; a slot grows to fit a wider window up to the launch byte
+        budget. ``slot`` is None for a fresh buffer: none was free (a
+        re-plan sub-window, another repair at once) or the window is wider
+        than the budget. ``reused`` is whether nothing was allocated."""
+        with self._tele_lock:
+            slot = (self._free_slots.pop()
+                    if self._free_slots and nbytes <= _BATCH_BYTE_BUDGET
+                    else None)
+            reused = (slot is not None
+                      and self._gather_slots[slot].nbytes >= nbytes)
+            if reused:
+                self.telemetry.gather_buffer_reuses += 1
+                return self._gather_slots[slot][:nbytes], slot, True
+            self.telemetry.gather_buffer_allocs += 1
+        buf = np.empty(nbytes, np.uint8)
+        if slot is not None:
+            self._gather_slots[slot] = buf
+        return buf, slot, False
+
+    def _give_gather_slot(self, slot: int) -> None:
+        """Hand a window's gather slot back to the store."""
+        with self._tele_lock:
+            # Lowest index taken first: a repair's windows meet the slots
+            # in the same order each time, so a repeated repair finds every
+            # slot already grown to its windows.
+            self._free_slots.append(slot)
+            self._free_slots.sort(reverse=True)
 
     def _write_block(self, sid: int, block: int, data: np.ndarray) -> None:
         path = self._block_path(sid, block)
@@ -816,6 +896,10 @@ class StripeStore:
         disk reads, device launches and write-backs overlap. ``None``
         defaults to pipelining whenever ``cfg.pipeline_window > 0``;
         ``False`` is the synchronous fallback. Bit-identical either way.
+        The pipeline reads each window into one of two gather buffers the
+        store keeps across repairs; ``gather_buffer_reuses`` and
+        ``gather_buffer_allocs`` count the windows that found one ready
+        and those that allocated.
         ``pipeline_hook`` is a diagnostic callback ``(stage, window_index)``
         (see ``repro.ftx.pipeline.PipelineHook``) used by the failure-
         injection tests.
@@ -1049,6 +1133,10 @@ class StripeStore:
             "local_reads": t.local_reads - before.local_reads,
             "remote_reads": t.remote_reads - before.remote_reads,
             "gather_bytes_per_shard": gather_shards,
+            "gather_buffer_reuses":
+                t.gather_buffer_reuses - before.gather_buffer_reuses,
+            "gather_buffer_allocs":
+                t.gather_buffer_allocs - before.gather_buffer_allocs,
             "schedule": schedule if batched else "none",
             "destinations": destinations,
             "blocks_relocated": t.blocks_relocated - before.blocks_relocated,

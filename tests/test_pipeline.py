@@ -201,6 +201,133 @@ def test_node_failure_between_prefetch_and_launch_bit_identical(
         store.revive_node(node)
         store.revive_node(second)
         assert _all_blocks(store) == truth
+        # The re-plan gave its slots back: the same repair again, its disk
+        # really lost, reads every window into a kept buffer as it is.
+        again = _repair(store, node)
+        assert again["gather_buffer_allocs"] == 0
+        assert again["gather_buffer_reuses"] == again["windows"] > 0
+        assert _all_blocks(store) == truth
+
+
+# ------------------------------------------------- kept gather buffers
+def _lose(store, node):
+    """Fail ``node`` and delete its block files (the lost disk)."""
+    store.fail_node(node)
+    for sid, st in store.stripes.items():
+        for b, n in enumerate(st.node_of_block):
+            if n == node:
+                store._block_path(sid, b).unlink()
+
+
+def _repair(store, node, **opts):
+    _lose(store, node)
+    tele = store.repair_all(options=RepairOptions(pipeline=True, **opts))
+    store.revive_node(node)
+    return tele
+
+
+def _twin_arc_store(root):
+    """24 stripes on 14 nodes: the stride-7 arcs put the even stripes on
+    nodes 0-9 (block b on node b) and the odd ones on 7-13, 0-2. Nodes 3, 4
+    and 5 hold one data block of each even stripe only, so losing any of
+    them is one pattern of 12 stripes and three (4, 3, B) windows; node 1
+    holds data block 1 of the even stripes and G1 (block 8, six reads) of
+    the odd ones."""
+    cfg = StoreConfig(scheme="cp-azure", k=6, r=2, p=2, block_size=512,
+                      batch_stripes=8, pipeline_window=4, prefetch_threads=4)
+    store = StripeStore(root, cfg, num_nodes=14)
+    payload = np.random.default_rng(5).integers(
+        0, 256, 24 * cfg.k * cfg.block_size, dtype=np.uint8)
+    store.put("blob", payload.tobytes())
+    store.seal()
+    return store
+
+
+def test_repairs_reuse_two_kept_gather_buffers(tmp_path):
+    """Rotating single-node repairs read every window into the store's two
+    kept buffers: both are made by the first repair and reused after, a
+    wider window (a G1 loss) grows each once, and every rebuilt block is
+    the encoded reference."""
+    store = _twin_arc_store(tmp_path / "s")
+    truth = _all_blocks(store)
+    k, bsz = store.cfg.k, store.cfg.block_size
+    data = np.frombuffer(b"".join(truth[(2, b)] for b in range(k)),
+                         np.uint8).reshape(k, bsz)
+    assert np.array_equal(store.scheme.encode(data), np.stack(
+        [np.frombuffer(truth[(2, b)], np.uint8) for b in range(store.n)]))
+    tele = store.telemetry
+    for i, node in enumerate((3, 4, 5, 3)):
+        rep = _repair(store, node)
+        assert rep["windows"] == 3 and rep["patterns"] == 1
+        assert _all_blocks(store) == truth
+        assert tele.gather_buffer_allocs == 2
+        assert tele.gather_buffer_reuses == 3 * (i + 1) - 2
+        assert (rep["gather_buffer_allocs"], rep["gather_buffer_reuses"]) \
+            == ((2, 1) if i == 0 else (0, 3))
+    rep = _repair(store, 1)                  # (4, 6, B) windows come first
+    assert rep["windows"] == 6 and rep["patterns"] == 2
+    assert (rep["gather_buffer_allocs"], rep["gather_buffer_reuses"]) == (2, 4)
+    assert [s.nbytes for s in store._gather_slots] == [4 * 6 * bsz] * 2
+    assert _all_blocks(store) == truth
+    for node in (1, 4):
+        rep = _repair(store, node)
+        assert rep["gather_buffer_allocs"] == 0
+        assert _all_blocks(store) == truth
+    assert tele.gather_buffer_allocs == 4
+
+
+def test_stale_slot_bytes_never_reach_a_repair(tmp_path):
+    """Slots filled with a sentinel between repairs change no rebuilt byte;
+    a short surviving block file fails the repair with a ValueError naming
+    it (as the parent's failed broadcast did), and the slots come back."""
+    store = _twin_arc_store(tmp_path / "s")
+    truth = _all_blocks(store)
+    for node in (3, 1, 5, 1):
+        for slot in store._gather_slots:
+            slot.fill(0xA5)
+        _repair(store, node)
+        assert _all_blocks(store) == truth
+    path = store._block_path(4, 4)           # read to rebuild block 3
+    path.write_bytes(truth[(4, 4)][:300])
+    store.fail_node(3)
+    with pytest.raises(ValueError, match="s4_b4 holds 300 bytes"):
+        store.repair_all(options=RepairOptions(pipeline=True))
+    store.revive_node(3)
+    assert sorted(store._free_slots) == [0, 1]
+
+
+def test_read_block_into_charges_as_read_block(tmp_path):
+    """The in-place read and the array read of one block give the same bytes
+    and charge the same counters, local and remote, per gather shard."""
+    from repro.dist.placement import PlacementMap
+
+    store = _build(tmp_path / "s", stripes=4)
+    pm = PlacementMap.from_store(store, num_shards=2, remote_multiplier=3.0)
+    keys = ("blocks_read", "bytes_read", "sim_seconds", "local_reads",
+            "remote_reads", "gather_bytes_per_shard")
+    for sid, b in ((0, 0), (1, 7), (3, 9)):
+        for shard in (0, 1, None):
+            charged = []
+            out = np.zeros(store.cfg.block_size, np.uint8)
+            for read in (
+                    lambda: store._read_block(sid, b, shard=shard,
+                                              placement=pm),
+                    lambda: store._read_block_into(sid, b, out, shard=shard,
+                                                   placement=pm) or out):
+                before = store.telemetry.reset()
+                data = read()
+                charged.append({k: getattr(store.telemetry, k)
+                                for k in keys})
+                store.telemetry = before
+            assert charged[0] == charged[1]
+            assert np.array_equal(data, out) and charged[0]["blocks_read"]
+    node = store.stripes[0].node_of_block[1]
+    store._block_path(0, 1).unlink()
+    with pytest.raises(IOError):
+        store._read_block_into(0, 1, out)
+    store.fail_node(node)
+    with pytest.raises(IOError, match="down"):
+        store._read_block_into(0, 1, out)
 
 
 # ------------------------------------------------------------- sharding
@@ -246,6 +373,20 @@ def test_pipelined_sharded_repair_bit_identical(tmp_path):
     assert rep.device_launches == 8 * rep.launches
     rep_b = repair_failed_nodes(sb, [node], options=RepairOptions(pipeline=False))
     assert rep_b.devices == 1
+    assert _all_blocks(sa) == _all_blocks(sb)
+    # Each window's per-shard buffers were carved from a kept slot; a second
+    # repair, slots full of stale bytes, carves them again and allocates
+    # nothing.
+    t = sa.telemetry
+    assert t.gather_buffer_allocs + t.gather_buffer_reuses == rep.windows
+    assert t.gather_buffer_reuses > 0
+    for slot in sa._gather_slots:
+        slot.fill(0xA5)
+    with with_rules(make_mesh((8, 1), ("data", "model"))):
+        again = _repair(sa, node)
+    assert again["devices"] == 8
+    assert again["gather_buffer_allocs"] == 0
+    assert again["gather_buffer_reuses"] == again["windows"] == rep.windows
     assert _all_blocks(sa) == _all_blocks(sb)
 
 
