@@ -22,7 +22,8 @@ launch per call via ``repro.dist.stripes`` — with bit-identical results;
 
 Every launch runs in three steps, each a program span (``repro.obs``):
 ``repro.launch.h2d`` puts a host input on the device and waits for the copy
-(``execute`` splits this step out only under a trace),
+(``execute`` splits this step out only under a trace; a pre-sharded input
+was put, and spanned, by ``repro.dist.placement.assemble_shards``),
 ``repro.launch.device`` dispatches the program and waits for it, and
 ``repro.launch.d2h`` copies the result back. ``execute`` and ``encode``
 return host arrays.
@@ -96,13 +97,13 @@ class BatchedCodecEngine:
     @staticmethod
     def _to_device(batch, mr: Optional[MeshRules]) -> jax.Array:
         """The ``repro.launch.h2d`` step: a host batch goes onto the stripe
-        sharding (or the default device); a device array (e.g. pre-sharded
-        by ``repro.dist.placement.assemble_shards``) moves nothing here and
-        records 0 bytes. The copy is waited for only under a trace: a sync
-        between the copy and the dispatch costs about 1.2 ms a launch."""
+        sharding (or the default device); a device array moves nothing and
+        has no span here (one pre-sharded by
+        ``repro.dist.placement.assemble_shards`` had its copy spanned
+        there). The copy is waited for only under a trace: a sync between
+        the copy and the dispatch costs about 1.2 ms a launch."""
         if not isinstance(batch, np.ndarray):
-            with obs.span("repro.launch.h2d", bytes=0):
-                return obs.block_if_tracing(batch)
+            return batch
         with obs.span("repro.launch.h2d", bytes=batch.nbytes):
             sharding = (stripe_sharding(batch.shape, mr)
                         if stripe_span(batch.shape, mr) > 1 else None)

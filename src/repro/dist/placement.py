@@ -30,6 +30,8 @@ from typing import Callable, Optional, Sequence
 import jax
 import numpy as np
 
+from repro import obs
+
 from .sharding import MeshRules
 from .stripes import stripe_sharding, stripe_span
 
@@ -263,9 +265,20 @@ def assemble_shards(shape: Sequence[int], mr: MeshRules,
         device), and the global array is stitched from the on-device
         shards — the single-host gather + device-0 bounce the old read
         path paid is gone.
+
+    The puts are the launch's host-to-device copy: the program span
+    ``repro.launch.h2d`` with ``bytes`` (summed over the puts) and
+    ``shards`` (how many). Under a trace the span waits for the copies to
+    land; with none running nothing waits here. A device may still be
+    reading ``bufs`` after the return: keep them unchanged until the
+    array is ready (``jax.block_until_ready``).
     """
     shape = tuple(shape)
     sharding = stripe_sharding(shape, mr)
-    arrays = [jax.device_put(buf, dev)
-              for sl, buf in zip(layout, bufs) for dev in sl.devices]
-    return jax.make_array_from_single_device_arrays(shape, sharding, arrays)
+    puts = [(buf, dev) for sl, buf in zip(layout, bufs) for dev in sl.devices]
+    with obs.span("repro.launch.h2d", bytes=sum(b.nbytes for b, _ in puts),
+                  shards=len(puts)):
+        arrays = obs.block_if_tracing(
+            [jax.device_put(buf, dev) for buf, dev in puts])
+        return jax.make_array_from_single_device_arrays(shape, sharding,
+                                                        arrays)

@@ -56,7 +56,8 @@ Every stage adds its wall time to :class:`PipelineResult`, so overlap is
 piece is also a program span (``repro.obs``): ``repro.repair.prefetch``,
 ``.gather_wait``, ``.launch`` and ``.writeback`` (``repro.encode.*`` for the
 encode pipeline), ``repro.pipeline.release`` for freeing a consumed
-window's input and ``repro.pipeline.drain_wait`` for the last write-backs.
+window's input, ``repro.pipeline.drain_wait`` for the last write-backs and
+``repro.pipeline.shutdown`` for joining a repair's threads.
 """
 from __future__ import annotations
 
@@ -67,6 +68,7 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor, wait
 from typing import Callable, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro import obs
@@ -162,7 +164,8 @@ class _Fetch:
     single device, one buffer). With a layout, ``bufs[i]`` is shard *i*'s
     slice of the ``(S, |reads|, B)`` batch, filled only by that shard's
     reader pool. The buffers are views of the store's gather ``slot``
-    (None: a fresh buffer of this window's own).
+    (None: a fresh buffer of this window's own). ``batch`` is the global
+    array the shards were put into, once assembled.
     """
     window: RepairWindow
     shape: tuple[int, int, int]
@@ -171,6 +174,7 @@ class _Fetch:
     futures: list[Future]
     t_submit: float
     slot: Optional[int]
+    batch: Optional[object] = None         # jax.Array
 
 
 @dataclasses.dataclass
@@ -182,6 +186,7 @@ class PipelineResult:
     device_launches: int = 0
     replans: int = 0
     read_seconds: float = 0.0              # sum of per-window prefetch spans
+    put_seconds: float = 0.0               # sum of the shard puts (sharded)
     compute_seconds: float = 0.0           # sum of launch (+ host copy) spans
     write_seconds: float = 0.0             # sum of write-back spans
     wall_seconds: float = 0.0
@@ -316,7 +321,12 @@ class RepairPipeline:
 
     def _release(self, fetch: _Fetch) -> None:
         """Give a consumed window's gather slot back to the store: its
-        launch has returned (or it re-planned), so nothing reads it."""
+        launch has returned (or it re-planned). The shard puts copy from
+        the slot's views asynchronously, so they are waited for first: the
+        next window's reads never overwrite bytes a put is still copying."""
+        if fetch.batch is not None:
+            jax.block_until_ready(fetch.batch)
+            fetch.batch = None
         if fetch.slot is not None:
             self._held.remove(fetch)
             self.store._give_gather_slot(fetch.slot)
@@ -327,7 +337,8 @@ class RepairPipeline:
         the per-shard buffers — or None when node deaths invalidated it
         (the window must re-plan). Non-I/O errors raise. The read stage
         runs from the prefetch's submit to here; the coordinator's own
-        blocked part is the ``repro.repair.gather_wait`` span."""
+        blocked part is the ``repro.repair.gather_wait`` span. Putting the
+        shards on their devices is the put stage."""
         with obs.span("repro.repair.gather_wait", window=fetch.window.index,
                       bytes=int(np.prod(fetch.shape))):
             wait(fetch.futures)
@@ -346,8 +357,11 @@ class RepairPipeline:
             return None
         if fetch.layout is None:
             return fetch.bufs[0]
-        return assemble_shards(fetch.shape, self.mesh_rules, fetch.layout,
-                               fetch.bufs)
+        t0 = time.perf_counter()
+        fetch.batch = assemble_shards(fetch.shape, self.mesh_rules,
+                                      fetch.layout, fetch.bufs)
+        _add_seconds(self._span_lock, res, "put", time.perf_counter() - t0)
+        return fetch.batch
 
     def _launch(self, win: RepairWindow, stacked,
                 res: PipelineResult) -> dict[int, np.ndarray]:
@@ -457,6 +471,11 @@ class RepairPipeline:
 
             run_double_buffered(windows, produce=produce, consume=consume,
                                 writer=writer, release=self._release)
+            # Joining a reader pool per shard and the writer takes
+            # milliseconds a repair; the span names that time.
+            with obs.span("repro.pipeline.shutdown",
+                          threads=num_pools * self.threads + 1):
+                stack.close()
         res.wall_seconds = time.perf_counter() - t_run
         return res
 

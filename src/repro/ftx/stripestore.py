@@ -159,6 +159,9 @@ class Telemetry:
     read_seconds: float = 0.0
     compute_seconds: float = 0.0
     write_seconds: float = 0.0
+    # Coordinator time putting a sharded launch's per-shard buffers on
+    # their devices (repro.dist.placement.assemble_shards); 0 unsharded.
+    put_seconds: float = 0.0
     # Locality accounting (PlacementMap): reads served from the reading
     # shard's own nodes vs. cross-shard fetches, and how many gather bytes
     # each shard pulled from disk during repair gathers.
@@ -202,6 +205,7 @@ class Telemetry:
         self.repairs_local = self.repairs_global = 0
         self.sim_seconds = 0.0
         self.read_seconds = self.compute_seconds = self.write_seconds = 0.0
+        self.put_seconds = 0.0
         self.local_reads = self.remote_reads = 0
         self.gather_bytes_per_shard = {}
         self.gather_buffer_reuses = self.gather_buffer_allocs = 0
@@ -910,7 +914,9 @@ class StripeStore:
         device span seen) and ``device_launches`` (total per-device kernel
         executions across all launches). ``read/compute/write_seconds``
         report per-stage wall spans; ``overlap_seconds`` is the stage time
-        the pipeline hid (0 on the synchronous paths).
+        the pipeline hid (0 on the synchronous paths). ``put_seconds`` is
+        the coordinator's time putting sharded launches' per-shard buffers
+        on their devices (0 where no launch is sharded).
 
         ``placement`` (a ``repro.dist.placement.PlacementMap``; defaults to
         the store's, else one derived from the node->shard default for the
@@ -1065,6 +1071,7 @@ class StripeStore:
             sched_total += res.schedule_total
             with self._tele_lock:
                 self.telemetry.read_seconds += res.read_seconds
+                self.telemetry.put_seconds += res.put_seconds
                 self.telemetry.compute_seconds += res.compute_seconds
                 self.telemetry.write_seconds += res.write_seconds
         else:
@@ -1125,6 +1132,7 @@ class StripeStore:
             "sim_seconds": t.sim_seconds - before.sim_seconds,
             "wall_seconds": wall,
             "read_seconds": t.read_seconds - before.read_seconds,
+            "put_seconds": t.put_seconds - before.put_seconds,
             "compute_seconds": t.compute_seconds - before.compute_seconds,
             "write_seconds": t.write_seconds - before.write_seconds,
             "overlap_seconds": max(0.0, stage_sum - wall),
@@ -1193,6 +1201,8 @@ class StripeStore:
         one-buffer fast path (attributed to gather shard 0). Every read is
         charged local/remote against ``placement``. The reads are the
         coordinator's ``repro.repair.gather_wait`` span of launch ``index``.
+        Returns the batch and the seconds its shards took to put (0 for the
+        one-buffer path).
         """
         from repro.dist.placement import assemble_shards, plan_gather
 
@@ -1206,9 +1216,11 @@ class StripeStore:
                         part.buf[i, j] = self._read_block(
                             sid, b, shard=part.shard, placement=placement)
         if layout is None:
-            return parts[0].buf
-        return assemble_shards(shape, mesh_rules, layout,
-                               [p.buf for p in parts])
+            return parts[0].buf, 0.0
+        t0 = time.perf_counter()
+        stacked = assemble_shards(shape, mesh_rules, layout,
+                                  [p.buf for p in parts])
+        return stacked, time.perf_counter() - t0
 
     def _repair_group(self, sids: list[int], down: frozenset[int],
                       compiled, spare_of: Optional[dict[int, int]],
@@ -1224,8 +1236,8 @@ class StripeStore:
         to the pipelined path; ``index`` numbers the launch in its repair.
         Returns the device span of the launch."""
         t0 = time.perf_counter()
-        stacked = self._gather_group(sids, compiled.reads, mesh_rules,
-                                     placement, index)
+        stacked, put = self._gather_group(sids, compiled.reads, mesh_rules,
+                                          placement, index)
         t1 = time.perf_counter()
         with obs.span("repro.repair.launch", window=index,
                       stripes=len(sids)):
@@ -1239,7 +1251,8 @@ class StripeStore:
                                 dest_of)
         t3 = time.perf_counter()
         with self._tele_lock:
-            self.telemetry.read_seconds += t1 - t0
+            self.telemetry.read_seconds += t1 - t0 - put
+            self.telemetry.put_seconds += put
             self.telemetry.compute_seconds += t2 - t1
             self.telemetry.write_seconds += t3 - t2
         return self.engine.last_span

@@ -132,6 +132,9 @@ def test_pipelined_repair_spans_and_bytes(pipelined):
     assert len(writes) == rep["windows"]
     assert sum(s.args["bytes"] for s in writes) == rebuilt
     assert len(_named(spans, "repro.pipeline.drain_wait")) == 1
+    (shutdown,) = _named(spans, "repro.pipeline.shutdown")
+    assert shutdown.args == {"threads": store.cfg.prefetch_threads + 1}
+    assert shutdown.start >= _named(spans, "repro.pipeline.drain_wait")[0].end
     released = _named(spans, "repro.pipeline.release")
     assert sorted(s.args["window"] for s in released) \
         == list(range(rep["windows"]))
