@@ -22,11 +22,12 @@ launch per call via ``repro.dist.stripes`` — with bit-identical results;
 
 Every launch runs in three steps, each a program span (``repro.obs``):
 ``repro.launch.h2d`` puts a host input on the device and waits for the copy
-(``execute`` splits this step out only under a trace; a pre-sharded input
-was put, and spanned, by ``repro.dist.placement.assemble_shards``),
-``repro.launch.device`` dispatches the program and waits for it, and
-``repro.launch.d2h`` copies the result back. ``execute`` and ``encode``
-return host arrays.
+(``execute`` splits this step out only under a trace, and ``put`` runs it
+alone, for a caller that must have the device hold its own copy before it
+reuses the host buffer; a pre-sharded input was put, and spanned, by
+``repro.dist.placement.assemble_shards``), ``repro.launch.device``
+dispatches the program and waits for it, and ``repro.launch.d2h`` copies
+the result back. ``execute`` and ``encode`` return host arrays.
 """
 from __future__ import annotations
 
@@ -38,7 +39,7 @@ import numpy as np
 
 from repro import obs
 from repro.dist.sharding import MeshRules
-from repro.dist.stripes import stripe_sharding, stripe_span
+from repro.dist.stripes import put_copy, stripe_sharding, stripe_span
 from repro.kernels.ops import (default_backend, effective_backend,
                                encode_batch_op, gf_matmul_batch_op,
                                require_backend)
@@ -107,13 +108,22 @@ class BatchedCodecEngine:
         with obs.span("repro.launch.h2d", bytes=batch.nbytes):
             sharding = (stripe_sharding(batch.shape, mr)
                         if stripe_span(batch.shape, mr) > 1 else None)
-            return obs.block_if_tracing(jax.device_put(batch, sharding))
+            return obs.block_if_tracing(put_copy(batch, sharding))
 
     @staticmethod
     def _to_host(out: jax.Array) -> np.ndarray:
         """The ``repro.launch.d2h`` step: the result, copied to the host."""
         with obs.span("repro.launch.d2h", bytes=out.nbytes):
             return np.asarray(out)
+
+    def put(self, batch, mesh_rules: Optional[MeshRules] = None
+            ) -> jax.Array:
+        """Put a host ``(S, |reads|, B)`` stack on the device now, on the
+        stripe sharding a launch under ``mesh_rules`` reads it with (the
+        ``repro.launch.h2d`` step). The device value is a copy: the host
+        buffer may be refilled once the returned array is ready."""
+        return self._to_device(np.ascontiguousarray(batch, np.uint8),
+                               self._rules(mesh_rules))
 
     def execute(self, plan: CompiledPlan, stacked: jax.Array | np.ndarray,
                 mesh_rules: Optional[MeshRules] = None) -> np.ndarray:
@@ -123,10 +133,10 @@ class BatchedCodecEngine:
         The zero-copy entry point for callers that materialize the read
         stack themselves — skips the per-block gather/stack. ``stacked``
         may be a host numpy array (the stripe store's single-shard gather;
-        put straight onto the stripe sharding) or a pre-sharded global
-        ``jax.Array`` built per device shard
-        (``repro.dist.placement.assemble_shards``), which is consumed with
-        zero re-transfer — never bounced through one device.
+        put straight onto the stripe sharding), a device array from
+        :meth:`put`, or a pre-sharded global ``jax.Array`` built per device
+        shard (``repro.dist.placement.assemble_shards``), which is consumed
+        with zero re-transfer — never bounced through one device.
         """
         import jax.numpy as jnp
 

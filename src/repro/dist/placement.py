@@ -33,7 +33,7 @@ import numpy as np
 from repro import obs
 
 from .sharding import MeshRules
-from .stripes import stripe_sharding, stripe_span
+from .stripes import put_copy, stripe_sharding, stripe_span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -271,7 +271,8 @@ def assemble_shards(shape: Sequence[int], mr: MeshRules,
     ``shards`` (how many). Under a trace the span waits for the copies to
     land; with none running nothing waits here. A device may still be
     reading ``bufs`` after the return: keep them unchanged until the
-    array is ready (``jax.block_until_ready``).
+    array is ready (``jax.block_until_ready``), after which the device
+    holds a copy of its own (``repro.dist.stripes.put_copy``).
     """
     shape = tuple(shape)
     sharding = stripe_sharding(shape, mr)
@@ -279,6 +280,6 @@ def assemble_shards(shape: Sequence[int], mr: MeshRules,
     with obs.span("repro.launch.h2d", bytes=sum(b.nbytes for b, _ in puts),
                   shards=len(puts)):
         arrays = obs.block_if_tracing(
-            [jax.device_put(buf, dev) for buf, dev in puts])
+            [put_copy(buf, dev) for buf, dev in puts])
         return jax.make_array_from_single_device_arrays(shape, sharding,
                                                         arrays)

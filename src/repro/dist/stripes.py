@@ -18,9 +18,21 @@ import functools
 from typing import Callable, Optional
 
 import jax
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .sharding import MeshRules, _resolve
+
+
+def put_copy(buf: np.ndarray, dest=None) -> jax.Array:
+    """``jax.device_put(buf, dest)`` whose device value never shares
+    ``buf``'s memory, so ``buf`` may be refilled once the returned array is
+    ready. The CPU backend wraps a 64-byte-aligned host buffer in place
+    instead of copying it, so there ``buf`` is copied on the host first;
+    an accelerator's put is always a transfer."""
+    if jax.default_backend() == "cpu":
+        buf = np.array(buf)
+    return jax.device_put(buf, dest)
 
 
 def stripe_spec(shape, mr: MeshRules) -> P:

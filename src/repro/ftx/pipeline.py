@@ -24,15 +24,17 @@ This module overlaps the three stages with a classic double buffer over
   read still goes through ``StripeStore._read_block`` — node liveness and
   the simulated per-node latency/bandwidth model apply unchanged, with the
   ``PlacementMap`` charging cross-shard reads at the configured remote
-  multiplier — while window *i* runs through
-  ``BatchedCodecEngine.execute`` (zero re-transfer on the pre-sharded
+  multiplier — while window *i* is put on the device
+  (``BatchedCodecEngine.put``, or ``assemble_shards`` for the pre-sharded
   batch). Each block is read once, by ``StripeStore._read_block_into``,
-  straight into its place in the window's buffer, and that buffer is one of
-  the two the store keeps across windows and repairs: the double buffer
+  straight into its place in the window's buffer, and that buffer is one
+  of the two the store keeps across windows and repairs: the double buffer
   holds at most two windows' inputs, and a window's slot goes back once its
-  launch has returned;
-* write-back of window *i*'s rebuilt blocks happens on a dedicated writer
-  thread, overlapped with the launch of window *i+1*.
+  input is on the device;
+* the coordinator does not wait for the launch: a launch thread runs
+  window *i* through ``BatchedCodecEngine.execute`` (dispatch, wait for the
+  result, copy back) and a dedicated writer thread writes its rebuilt
+  blocks, overlapped with the reads and put of window *i+1*.
 
 Window creation runs the locality-aware stripe scheduler
 (``repro.dist.schedule``, ``schedule="locality"``): each window's sid list
@@ -54,13 +56,16 @@ Every stage adds its wall time to :class:`PipelineResult`, so overlap is
 *observable*: ``read+compute+write > wall`` is the pipeline working, and
 ``overlap_seconds`` quantifies it. Each stage that runs on one thread in one
 piece is also a program span (``repro.obs``): ``repro.repair.prefetch``,
-``.gather_wait``, ``.launch`` and ``.writeback`` (``repro.encode.*`` for the
-encode pipeline), ``repro.pipeline.release`` for freeing a consumed
-window's input, ``repro.pipeline.drain_wait`` for the last write-backs and
-``repro.pipeline.shutdown`` for joining a repair's threads.
+``.gather_wait``, ``.launch`` (twice a pipelined window: the put on the
+coordinator, then ``execute`` on the launch thread) and ``.writeback``
+(``repro.encode.*`` for the encode pipeline), ``repro.pipeline.release``
+for freeing a consumed window's input, ``repro.pipeline.drain_wait`` for
+the last write-backs and ``repro.pipeline.shutdown`` for joining a
+repair's threads.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import threading
@@ -93,7 +98,7 @@ def run_double_buffered(windows: Sequence, *, produce, consume,
 
     * ``produce(win)`` submits asynchronous production of ``win``'s input
       (reader-pool prefetch, host packing, ...) and returns a token;
-    * ``consume(win, token)`` waits the token out, runs the window's
+    * ``consume(win, token)`` waits the token out, starts the window's
       device work, and returns either ``None`` (the window was handled
       entirely inline — e.g. a repair re-plan) or a zero-argument drain
       callable;
@@ -127,24 +132,25 @@ def run_double_buffered(windows: Sequence, *, produce, consume,
         f.result()                       # surface writer-thread errors
 
 
-def _add_seconds(lock: threading.Lock, res: "PipelineResult", stage: str,
-                 seconds: float) -> None:
-    """Bump a stage's aggregate, under the result lock (stages land from
+def _add_seconds(lock: threading.Lock, res: "PipelineResult",
+                 stages: tuple[str, ...], seconds: float) -> None:
+    """Bump the stages' aggregates, under the result lock (stages land from
     the coordinator, packer and writer threads)."""
     with lock:
-        setattr(res, f"{stage}_seconds",
-                getattr(res, f"{stage}_seconds") + seconds)
+        for stage in stages:
+            setattr(res, f"{stage}_seconds",
+                    getattr(res, f"{stage}_seconds") + seconds)
 
 
 @contextlib.contextmanager
-def _stage_span(lock: threading.Lock, res: "PipelineResult", stage: str,
-                name: str, **args):
+def _stage_span(lock: threading.Lock, res: "PipelineResult",
+                stages: tuple[str, ...], name: str, **args):
     """One stage run in one piece: the program span ``name`` with ``args``,
-    whose wall time adds to ``res.<stage>_seconds``."""
+    whose wall time adds to ``res.<stage>_seconds`` of each stage."""
     t0 = time.perf_counter()
     with obs.span(name, **args):
         yield
-    _add_seconds(lock, res, stage, time.perf_counter() - t0)
+    _add_seconds(lock, res, stages, time.perf_counter() - t0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,8 +170,9 @@ class _Fetch:
     single device, one buffer). With a layout, ``bufs[i]`` is shard *i*'s
     slice of the ``(S, |reads|, B)`` batch, filled only by that shard's
     reader pool. The buffers are views of the store's gather ``slot``
-    (None: a fresh buffer of this window's own). ``batch`` is the global
-    array the shards were put into, once assembled.
+    (None: a fresh buffer of this window's own). ``batch`` is the device
+    array the buffers were put into, once put: assembled from the shards,
+    or the one-buffer stack put whole.
     """
     window: RepairWindow
     shape: tuple[int, int, int]
@@ -185,9 +192,17 @@ class PipelineResult:
     devices: int = 1
     device_launches: int = 0
     replans: int = 0
+    # Launches run (dispatched, waited for, copied back) on the launch
+    # thread, not on the coordinator.
+    deferred_launches: int = 0
     read_seconds: float = 0.0              # sum of per-window prefetch spans
     put_seconds: float = 0.0               # sum of the shard puts (sharded)
-    compute_seconds: float = 0.0           # sum of launch (+ host copy) spans
+    # Sum of the launches' host time (their repro.repair.launch spans): the
+    # coordinator's put, then dispatch, wait and copy back.
+    compute_seconds: float = 0.0
+    # The coordinator's part of it: putting a one-chip window's stack on the
+    # device before the launch thread takes it (~0 sharded: put_seconds).
+    stack_put_seconds: float = 0.0
     write_seconds: float = 0.0             # sum of write-back spans
     wall_seconds: float = 0.0
     # Stripe-scheduler predictions (repro.dist.schedule): shard-local reads
@@ -321,9 +336,10 @@ class RepairPipeline:
 
     def _release(self, fetch: _Fetch) -> None:
         """Give a consumed window's gather slot back to the store: its
-        launch has returned (or it re-planned). The shard puts copy from
-        the slot's views asynchronously, so they are waited for first: the
-        next window's reads never overwrite bytes a put is still copying."""
+        input is on the device (or it re-planned). The puts copy from the
+        slot's views asynchronously, so they are waited for first: the next
+        window's reads never overwrite bytes a put is still copying. The
+        launch itself may still be running; it reads the device copy."""
         if fetch.batch is not None:
             jax.block_until_ready(fetch.batch)
             fetch.batch = None
@@ -338,11 +354,12 @@ class RepairPipeline:
         (the window must re-plan). Non-I/O errors raise. The read stage
         runs from the prefetch's submit to here; the coordinator's own
         blocked part is the ``repro.repair.gather_wait`` span. Putting the
-        shards on their devices is the put stage."""
+        shards on their devices is the put stage; a host stack is put by
+        :meth:`_put`."""
         with obs.span("repro.repair.gather_wait", window=fetch.window.index,
                       bytes=int(np.prod(fetch.shape))):
             wait(fetch.futures)
-        _add_seconds(self._span_lock, res, "read",
+        _add_seconds(self._span_lock, res, ("read",),
                      time.perf_counter() - fetch.t_submit)
         io_failed = False
         for f in fetch.futures:
@@ -360,25 +377,50 @@ class RepairPipeline:
         t0 = time.perf_counter()
         fetch.batch = assemble_shards(fetch.shape, self.mesh_rules,
                                       fetch.layout, fetch.bufs)
-        _add_seconds(self._span_lock, res, "put", time.perf_counter() - t0)
+        _add_seconds(self._span_lock, res, ("put",),
+                     time.perf_counter() - t0)
         return fetch.batch
+
+    def _put(self, fetch: _Fetch, stacked, res: PipelineResult):
+        """The coordinator's part of a pipelined window's launch (its
+        ``repro.repair.launch`` span, and the stack-put stage): a host
+        stack is put on the device explicitly, and waited for, so that the
+        slot can go back now that the device holds a copy of its own.
+        Handed to a launch that has not run yet as the host array itself,
+        it could be read after the next window's reads had begun landing
+        in it. An assembled sharded batch is returned as it is. Returns the
+        device stack for :meth:`_launch`."""
+        win = fetch.window
+        with _stage_span(self._span_lock, res, ("compute", "stack_put"),
+                         "repro.repair.launch", window=win.index,
+                         stripes=len(win.sids)):
+            if isinstance(stacked, np.ndarray):
+                stacked = fetch.batch = jax.block_until_ready(
+                    self.store.engine.put(stacked, self.mesh_rules))
+        return stacked
 
     def _launch(self, win: RepairWindow, stacked,
                 res: PipelineResult) -> dict[int, np.ndarray]:
+        """Run a window's launch (``BatchedCodecEngine.execute``: dispatch,
+        wait, copy back), on the launch thread for a pipelined window and
+        on the coordinator for a re-planned one. Returns the rebuilt blocks
+        by target."""
         engine = self.store.engine
-        with _stage_span(self._span_lock, res, "compute",
+        with _stage_span(self._span_lock, res, ("compute",),
                          "repro.repair.launch", window=win.index,
                          stripes=len(win.sids)):
             out = np.asarray(engine.execute(win.compiled, stacked,
                                             self.mesh_rules))
-        res.launches += 1
-        res.devices = max(res.devices, engine.last_span)
-        res.device_launches += engine.last_span
+            devices = engine.last_span
+        with self._span_lock:
+            res.launches += 1
+            res.devices = max(res.devices, devices)
+            res.device_launches += devices
         return {b: out[:, t, :] for t, b in enumerate(win.compiled.targets)}
 
     def _writeback(self, win: RepairWindow, rebuilt: dict[int, np.ndarray],
                    res: PipelineResult) -> None:
-        with _stage_span(self._span_lock, res, "write",
+        with _stage_span(self._span_lock, res, ("write",),
                          "repro.repair.writeback", window=win.index,
                          bytes=sum(v.nbytes for v in rebuilt.values())):
             self.store._finish_repair(list(win.sids), win.down,
@@ -429,9 +471,14 @@ class RepairPipeline:
         accounting into ``res``.
 
         The double buffer: wait on window *i*'s prefetch, immediately
-        submit window *i+1*'s, then launch *i* and hand its write-back to
-        the writer thread — so at steady state reads, compute and writes
-        for three consecutive windows run concurrently.
+        submit window *i+1*'s, then put *i* on the device and hand its
+        launch to the launch thread and its write-back to the writer thread
+        — so at steady state reads, compute and writes for three
+        consecutive windows run concurrently, and the coordinator never
+        waits on the device. It waits only before handing off window *i*
+        while window *i-2*'s launch has not returned, so the device holds
+        at most two windows' launches; only those two launches' futures are
+        kept, so a rebuilt window is freed once it is written back.
         """
         res.windows = len(windows)
         if not windows:
@@ -453,6 +500,16 @@ class RepairPipeline:
                 for s in range(num_pools)]
             writer = stack.enter_context(ThreadPoolExecutor(
                 1, thread_name_prefix="repair-write"))
+            launcher = stack.enter_context(ThreadPoolExecutor(
+                1, thread_name_prefix="repair-launch"))
+            launches: collections.deque[Future] = collections.deque(
+                maxlen=2)
+
+            def deferred(win: RepairWindow, stacked):
+                rebuilt = self._launch(win, stacked, res)
+                with self._span_lock:
+                    res.deferred_launches += 1
+                return rebuilt
 
             def produce(win: RepairWindow) -> _Fetch:
                 fetch = self._prefetch(readers, win)
@@ -465,16 +522,20 @@ class RepairPipeline:
                 if stacked is None:
                     self._replan(readers, win, res)
                     return None
-                rebuilt = self._launch(win, stacked, res)
+                if len(launches) == launches.maxlen:
+                    wait([launches[0]])
+                launched = launcher.submit(
+                    deferred, win, self._put(fetch, stacked, res))
+                launches.append(launched)
                 self.hook("writeback", win.index)
-                return lambda: self._writeback(win, rebuilt, res)
+                return lambda: self._writeback(win, launched.result(), res)
 
             run_double_buffered(windows, produce=produce, consume=consume,
                                 writer=writer, release=self._release)
-            # Joining a reader pool per shard and the writer takes
-            # milliseconds a repair; the span names that time.
+            # Joining a reader pool per shard, the writer and the launch
+            # thread takes milliseconds a repair; the span names that time.
             with obs.span("repro.pipeline.shutdown",
-                          threads=num_pools * self.threads + 1):
+                          threads=num_pools * self.threads + 2):
                 stack.close()
         res.wall_seconds = time.perf_counter() - t_run
         return res
@@ -564,7 +625,7 @@ class EncodePipeline:
     def _encode(self, win: EncodeWindow, batch: np.ndarray,
                 res: PipelineResult) -> np.ndarray:
         engine = self.store.engine
-        with _stage_span(self._span_lock, res, "compute",
+        with _stage_span(self._span_lock, res, ("compute",),
                          "repro.encode.launch", window=win.index,
                          stripes=win.count):
             out = np.asarray(engine.encode(batch, self.mesh_rules))
@@ -575,7 +636,7 @@ class EncodePipeline:
 
     def _drain(self, stream, win: EncodeWindow, encoded: np.ndarray,
                res: PipelineResult) -> None:
-        with _stage_span(self._span_lock, res, "write",
+        with _stage_span(self._span_lock, res, ("write",),
                          "repro.encode.writeback", window=win.index,
                          bytes=encoded.nbytes):
             stream.write_window(win.first, encoded)
@@ -612,7 +673,7 @@ class EncodePipeline:
                 fut, t0 = token
                 with obs.span("repro.encode.pack_wait", window=win.index):
                     batch = fut.result()
-                _add_seconds(self._span_lock, res, "read",
+                _add_seconds(self._span_lock, res, ("read",),
                              time.perf_counter() - t0)
                 encoded = self._encode(win, batch, res)
                 self.hook("encode", win.index)

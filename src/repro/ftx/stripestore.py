@@ -162,6 +162,10 @@ class Telemetry:
     # Coordinator time putting a sharded launch's per-shard buffers on
     # their devices (repro.dist.placement.assemble_shards); 0 unsharded.
     put_seconds: float = 0.0
+    # The pipeline coordinator's part of compute_seconds: putting a
+    # one-chip window's stack on the device before its launch thread runs
+    # the launch; 0 on the synchronous paths (put_seconds when sharded).
+    stack_put_seconds: float = 0.0
     # Locality accounting (PlacementMap): reads served from the reading
     # shard's own nodes vs. cross-shard fetches, and how many gather bytes
     # each shard pulled from disk during repair gathers.
@@ -205,7 +209,7 @@ class Telemetry:
         self.repairs_local = self.repairs_global = 0
         self.sim_seconds = 0.0
         self.read_seconds = self.compute_seconds = self.write_seconds = 0.0
-        self.put_seconds = 0.0
+        self.put_seconds = self.stack_put_seconds = 0.0
         self.local_reads = self.remote_reads = 0
         self.gather_bytes_per_shard = {}
         self.gather_buffer_reuses = self.gather_buffer_allocs = 0
@@ -916,7 +920,14 @@ class StripeStore:
         report per-stage wall spans; ``overlap_seconds`` is the stage time
         the pipeline hid (0 on the synchronous paths). ``put_seconds`` is
         the coordinator's time putting sharded launches' per-shard buffers
-        on their devices (0 where no launch is sharded).
+        on their devices (0 where no launch is sharded). The pipeline's
+        coordinator puts each window's input on the device and hands the
+        launch to a launch thread, which dispatches it, waits for it and
+        copies it back: ``stack_put_seconds`` is the coordinator's part of
+        ``compute_seconds``, its put of one-chip windows (0 on the
+        synchronous paths), and ``deferred_launches`` counts the launches
+        the launch thread ran (re-planned and synchronous launches are not
+        counted).
 
         ``placement`` (a ``repro.dist.placement.PlacementMap``; defaults to
         the store's, else one derived from the node->shard default for the
@@ -996,6 +1007,7 @@ class StripeStore:
         device_launches = 0
         windows = 0
         replans = 0
+        deferred = 0
         # Stripe-scheduler prediction accumulators: local reads the chosen
         # order will serve shard-locally vs. what the contiguous order
         # would have, over the same total (repro.dist.schedule).
@@ -1066,6 +1078,7 @@ class StripeStore:
             device_launches += res.device_launches
             windows = res.windows
             replans = res.replans
+            deferred = res.deferred_launches
             sched_local += res.scheduled_local
             contig_local += res.contiguous_local
             sched_total += res.schedule_total
@@ -1073,6 +1086,7 @@ class StripeStore:
                 self.telemetry.read_seconds += res.read_seconds
                 self.telemetry.put_seconds += res.put_seconds
                 self.telemetry.compute_seconds += res.compute_seconds
+                self.telemetry.stack_put_seconds += res.stack_put_seconds
                 self.telemetry.write_seconds += res.write_seconds
         else:
             for sids, down, compiled in work:
@@ -1127,6 +1141,7 @@ class StripeStore:
             "pipelined": bool(use_pipeline and work),
             "windows": windows,
             "replans": replans,
+            "deferred_launches": deferred,
             "blocks_read": t.blocks_read - before.blocks_read,
             "bytes_read": t.bytes_read - before.bytes_read,
             "sim_seconds": t.sim_seconds - before.sim_seconds,
@@ -1134,6 +1149,8 @@ class StripeStore:
             "read_seconds": t.read_seconds - before.read_seconds,
             "put_seconds": t.put_seconds - before.put_seconds,
             "compute_seconds": t.compute_seconds - before.compute_seconds,
+            "stack_put_seconds": (t.stack_put_seconds
+                                  - before.stack_put_seconds),
             "write_seconds": t.write_seconds - before.write_seconds,
             "overlap_seconds": max(0.0, stage_sum - wall),
             "repairs_local": t.repairs_local - before.repairs_local,

@@ -233,6 +233,40 @@ def test_bit_plane_batched_kernels_lockstep(rng):
             assert (got == want).all(), (backend, S, t, R, B)
 
 
+# ------------------------------------------------------------- put
+@pytest.mark.parametrize("backend", ["gf", "crs", "mxu"])
+def test_execute_on_a_put_stack_matches_the_host_stack(backend, rng,
+                                                       monkeypatch):
+    """``execute`` gives the same bytes on a host stack and on one ``put``
+    first; untraced, it hands a host stack to the jit as it is, with no
+    explicit ``jax.device_put``, and ``put``'s device value is a copy that
+    outlives the host stack's bytes."""
+    import jax
+
+    s = make_scheme("cp-azure", 6, 2, 2)
+    engine = BatchedCodecEngine(s, backend=backend)
+    plan = engine.planner.multi_plan({0, s.k})
+    stack = rng.integers(0, 256, (3, len(plan.reads), 128), dtype=np.uint8)
+    puts = []
+    device_put = jax.device_put
+
+    def counted(*args, **kw):
+        puts.append(args[0])
+        return device_put(*args, **kw)
+
+    monkeypatch.setattr(jax, "device_put", counted)
+    want = engine.execute(plan, stack)
+    assert puts == []
+    assert isinstance(want, np.ndarray)
+    assert want.shape == (3, len(plan.targets), 128)
+    on_device = engine.put(stack)
+    assert isinstance(on_device, jax.Array) and len(puts) == 1
+    stack.fill(0)                        # the device holds a copy of its own
+    assert np.array_equal(engine.execute(plan, on_device), want)
+    assert len(puts) == 1
+    assert engine.effective_backend and engine.last_span == 1
+
+
 # -------------------------------------------------------- store integration
 def test_store_batched_repair_bit_identical_and_ragged(tmp_path, rng):
     """Fleet repair through the store: batched and looped paths agree on

@@ -120,9 +120,11 @@ def test_pipelined_repair_spans_and_bytes(pipelined):
     assert len(reads) == rep["blocks_read"]
     assert sum(s.args["bytes"] for s in reads) == rep["bytes_read"]
     assert {s.thread for s in reads} != {plan.thread}   # on reader threads
-    for stage in ("repro.repair.launch", "repro.launch.h2d",
-                  "repro.launch.device", "repro.launch.d2h"):
+    for stage in ("repro.launch.h2d", "repro.launch.device",
+                  "repro.launch.d2h"):
         assert len(_named(spans, stage)) == rep["launches"], stage
+    # two a launch: the coordinator's, then the launch thread's
+    assert len(_named(spans, "repro.repair.launch")) == 2 * rep["launches"]
     assert sum(s.args["bytes"] for s in _named(spans, "repro.launch.h2d")) \
         == rep["bytes_read"]
     rebuilt = rep["stripes_repaired"] * bs         # one lost block a stripe
@@ -133,7 +135,7 @@ def test_pipelined_repair_spans_and_bytes(pipelined):
     assert sum(s.args["bytes"] for s in writes) == rebuilt
     assert len(_named(spans, "repro.pipeline.drain_wait")) == 1
     (shutdown,) = _named(spans, "repro.pipeline.shutdown")
-    assert shutdown.args == {"threads": store.cfg.prefetch_threads + 1}
+    assert shutdown.args == {"threads": store.cfg.prefetch_threads + 2}
     assert shutdown.start >= _named(spans, "repro.pipeline.drain_wait")[0].end
     released = _named(spans, "repro.pipeline.release")
     assert sorted(s.args["window"] for s in released) \
@@ -141,17 +143,30 @@ def test_pipelined_repair_spans_and_bytes(pipelined):
 
 
 def test_launch_triple_nests_in_the_pipeline_launch(pipelined):
+    """A window's launch is two ``repro.repair.launch`` spans: the
+    coordinator's holds the put, and the launch thread's, after it, holds
+    the dispatch and wait for the result, then the copy back."""
     store, spans, rep = pipelined
-    for launch in _named(spans, "repro.repair.launch"):
-        assert launch.thread == _named(spans, "repro.repair.plan")[0].thread
-        (device,) = _inside(launch, spans, "repro.launch.device")
+    coordinator = _named(spans, "repro.repair.plan")[0].thread
+    launches = _named(spans, "repro.repair.launch")
+    puts = {s.args["window"]: s for s in launches if s.thread == coordinator}
+    runs = {s.args["window"]: s for s in launches if s.thread != coordinator}
+    assert len(puts) == len(runs) == rep["launches"] \
+        == rep["deferred_launches"]
+    assert len({s.thread for s in runs.values()}) == 1
+    for window, put in puts.items():
+        run = runs[window]
+        assert run.args == put.args and run.start >= put.end
+        (h2d,) = _inside(put, spans, "repro.launch.h2d")
+        (device,) = _inside(run, spans, "repro.launch.device")
+        (d2h,) = _inside(run, spans, "repro.launch.d2h")
         assert device.args["backend"] == store.cfg.backend
-        assert device.args["stripes"] == launch.args["stripes"]
+        assert device.args["stripes"] == put.args["stripes"]
         assert device.args["targets"] == 1
-        h2d, d2h = (_inside(launch, spans, f"repro.launch.{x}")
-                    for x in ("h2d", "d2h"))
-        assert len(h2d) == len(d2h) == 1
-        assert h2d[0].end <= device.start and device.end <= d2h[0].start
+        assert h2d.args["bytes"] == put.args["stripes"] \
+            * device.args["reads"] * store.cfg.block_size
+        assert d2h.args["bytes"] == put.args["stripes"] * store.cfg.block_size
+        assert device.end <= d2h.start
 
 
 def test_sync_repair_spans(tmp_path):

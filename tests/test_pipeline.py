@@ -10,6 +10,9 @@ import os
 import subprocess
 import sys
 import tempfile
+import threading
+import time
+import weakref
 from pathlib import Path
 
 import jax
@@ -18,8 +21,8 @@ import pytest
 
 from hypothesis import given, settings, strategies as st
 
-from repro.ftx import (FailureInjector, RepairOptions, StoreConfig,
-                       StripeStore, repair_failed_nodes)
+from repro.ftx import (FailureInjector, RepairOptions, RepairPipeline,
+                       StoreConfig, StripeStore, repair_failed_nodes)
 from repro.launch.mesh import make_mesh
 
 REPO = Path(__file__).resolve().parent.parent
@@ -294,6 +297,179 @@ def test_stale_slot_bytes_never_reach_a_repair(tmp_path):
         store.repair_all(options=RepairOptions(pipeline=True))
     store.revive_node(3)
     assert sorted(store._free_slots) == [0, 1]
+
+
+# ------------------------------------------------------- deferred launch
+def test_back_to_back_deferred_repairs_rebuild_every_byte(tmp_path,
+                                                          monkeypatch):
+    """Each window is put on the device by the coordinator and launched on
+    the launch thread, while its slot goes back once the input is on the
+    device: repairs run back to back over three or more windows each, every
+    slot overwritten the moment it comes back (as if the next window's
+    reads landed at once), rebuild every block as it was, and every
+    pipelined launch is deferred. The slots are page-aligned, which the CPU
+    backend wraps in place rather than copy: handed to the launch as numpy,
+    or put without a copy, a slot is read by the launch after the
+    overwrite."""
+    cfg = StoreConfig(scheme="cp-azure", k=6, r=2, p=2, block_size=1 << 16,
+                      batch_stripes=8, pipeline_window=4, prefetch_threads=4)
+    store = StripeStore(tmp_path / "s", cfg, num_nodes=14)
+    store.put("blob", np.random.default_rng(7).integers(
+        0, 256, 24 * cfg.k * cfg.block_size, dtype=np.uint8).tobytes())
+    store.seal()
+    truth = _all_blocks(store)
+    widest = 4 * 6 * cfg.block_size          # a (4, 6, B) window of a G1 loss
+    for i in range(2):
+        raw = np.empty(widest + 4096, np.uint8)
+        lo = -raw.ctypes.data % 4096
+        store._gather_slots[i] = raw[lo:lo + widest]
+    give = store._give_gather_slot
+
+    def give_and_overwrite(slot):
+        store._gather_slots[slot].fill(0x5A)
+        give(slot)
+
+    monkeypatch.setattr(store, "_give_gather_slot", give_and_overwrite)
+    for node in (3, 4, 5, 1, 3, 1, 4):
+        rep = _repair(store, node)
+        assert rep["gather_buffer_allocs"] == 0
+        assert rep["windows"] >= 3 and rep["replans"] == 0
+        assert rep["deferred_launches"] == rep["launches"] == rep["windows"]
+        assert _all_blocks(store) == truth
+    assert sorted(store._free_slots) == [0, 1]
+
+
+def test_failed_launch_surfaces_and_gives_both_slots_back(tmp_path,
+                                                          monkeypatch):
+    """A launch that fails on the launch thread surfaces from
+    ``repair_all``, as the writer's errors do, after the other windows ran,
+    and both kept slots come back; the next repair is whole."""
+    store = _twin_arc_store(tmp_path / "s")
+    truth = _all_blocks(store)
+    execute = store.engine.execute
+    calls = []
+
+    def failing(plan, stacked, mesh_rules=None):
+        calls.append(threading.current_thread().name)
+        if len(calls) == 2:
+            raise RuntimeError("device lost")
+        return execute(plan, stacked, mesh_rules)
+
+    monkeypatch.setattr(store.engine, "execute", failing)
+    _lose(store, 3)
+    with pytest.raises(RuntimeError, match="device lost"):
+        store.repair_all(options=RepairOptions(pipeline=True))
+    assert len(calls) == 3                   # every window was launched
+    assert all(name.startswith("repair-launch") for name in calls)
+    assert sorted(store._free_slots) == [0, 1]
+    monkeypatch.setattr(store.engine, "execute", execute)
+    rep = store.repair_all(options=RepairOptions(pipeline=True))
+    store.revive_node(3)
+    assert rep["gather_buffer_allocs"] == 0
+    assert _all_blocks(store) == truth
+
+
+def test_deferred_launch_report(tmp_path, monkeypatch):
+    """Pipelined launches are deferred and the coordinator's put of their
+    stacks is within their host time; synchronous and re-planned launches
+    are not deferred and put no stack before their launch."""
+    store = _build(tmp_path / "s", window=0)
+    truth = _all_blocks(store)
+    node = store.stripes[0].node_of_block[0]
+    store.fail_node(node)
+    sync = store.repair_all()
+    assert not sync["pipelined"] and sync["launches"] > 0
+    assert sync["deferred_launches"] == 0
+    assert sync["stack_put_seconds"] == 0 < sync["compute_seconds"]
+    rep = store.repair_all(options=RepairOptions(pipeline=True))
+    assert rep["pipelined"] and rep["launches"] == rep["windows"] > 1
+    assert rep["deferred_launches"] == rep["launches"]
+    assert 0 < rep["stack_put_seconds"] <= rep["compute_seconds"]
+    # One read of the first window fails: that window re-plans (the same
+    # down set, one sub-window launched and collected on the coordinator).
+    read_into = store._read_block_into
+    failed = []
+
+    def flaky(sid, b, out, **kw):
+        if not failed:
+            failed.append((sid, b))
+            raise IOError("read lost")
+        return read_into(sid, b, out, **kw)
+
+    monkeypatch.setattr(store, "_read_block_into", flaky)
+    rep = store.repair_all(options=RepairOptions(pipeline=True))
+    assert failed and rep["replans"] == 1
+    assert rep["launches"] == rep["windows"]
+    assert rep["deferred_launches"] == rep["launches"] - 1
+    assert 0 < rep["stack_put_seconds"] <= rep["compute_seconds"]
+    store.revive_node(node)
+    assert _all_blocks(store) == truth
+
+
+def test_rebuilt_windows_are_freed_once_written_back(tmp_path,
+                                                     monkeypatch):
+    """The pipeline keeps only the launches it still waits on: when window
+    *i* is about to be handed off, every window before *i-2* that was
+    written back has had its rebuilt output freed, so a repair holds a few
+    windows' outputs in host memory however many windows it has."""
+    store = _build(tmp_path / "s", window=2)
+    outs = []
+    execute = store.engine.execute
+
+    def recorded(plan, stacked, mesh_rules=None):
+        out = execute(plan, stacked, mesh_rules)
+        outs.append(weakref.ref(out))
+        return out
+
+    monkeypatch.setattr(store.engine, "execute", recorded)
+    written = []
+    writeback = RepairPipeline._writeback
+
+    def counted(self, win, rebuilt, res):
+        writeback(self, win, rebuilt, res)
+        written.append(win.index)
+
+    monkeypatch.setattr(RepairPipeline, "_writeback", counted)
+    checked = []
+    kept = []
+
+    def hook(stage, index):
+        if stage != "launch":
+            return
+        done = [k for k in list(written) if k < index - 2]
+        deadline = time.monotonic() + 2.0
+        alive = [k for k in done if outs[k]() is not None]
+        while alive and time.monotonic() < deadline:
+            time.sleep(1e-3)
+            alive = [k for k in done if outs[k]() is not None]
+        checked.extend(done)
+        kept.extend(alive)
+
+    rep = _repair(store, 0, pipeline_hook=hook)
+    assert rep["launches"] == rep["windows"] == len(outs) >= 10
+    assert rep["replans"] == 0
+    assert len(set(checked)) >= rep["windows"] // 2
+    assert kept == []
+
+
+def test_launch_accounting_under_thread_switch_stress(tmp_path):
+    """The coordinator, the launch thread and the writer bump one result;
+    with the interpreter switching threads as often as it can and more
+    readers than cores, no count is lost and every byte is rebuilt."""
+    cores = len(os.sched_getaffinity(0))
+    store = _build(tmp_path / "s", window=2, threads=min(2 * cores, 64))
+    truth = _all_blocks(store)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for node in (0, 5, 9):
+            rep = _repair(store, node)
+            assert rep["launches"] == rep["windows"] >= 10
+            assert rep["deferred_launches"] == rep["launches"]
+            assert rep["device_launches"] == rep["launches"]
+            assert _all_blocks(store) == truth
+    finally:
+        sys.setswitchinterval(interval)
 
 
 def test_read_block_into_charges_as_read_block(tmp_path):

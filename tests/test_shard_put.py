@@ -110,7 +110,8 @@ for mode, pipeline in (("pipelined", True), ("sync", False)):
         jax.profiler.stop_trace()
     out[mode] = {"reports": [{k: r[k] for k in (
         "put_seconds", "pipelined", "launches", "devices", "device_launches",
-        "bytes_read", "gather_buffer_reuses", "gather_buffer_allocs")}
+        "deferred_launches", "bytes_read", "gather_buffer_reuses",
+        "gather_buffer_allocs")}
         for r in reps + [traced]],
         "h2d": h2d_spans(trace), "same_bytes": blocks(store) == truth}
 out["landed"] = landed
@@ -138,6 +139,8 @@ def test_sharded_repair_reports_its_put_and_matches_one_device(four_devices,
         assert rep["pipelined"] == (mode == "pipelined")
         assert rep["devices"] == 4
         assert rep["device_launches"] == 4 * rep["launches"]
+        assert rep["deferred_launches"] \
+            == (rep["launches"] if mode == "pipelined" else 0)
         assert rep["put_seconds"] > 0
     assert four_devices["one"] == {"put_seconds": 0.0, "devices": 1}
 
