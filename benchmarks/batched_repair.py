@@ -3,9 +3,10 @@
 Sweeps S (stripes per batch) x B (block bytes) x scheme and times
 single-node and two-node repair through:
 
-  looped   — the seed path: ``StripeCodec.repair_single`` per stripe, one
-             kernel dispatch each (plan cache warm, so this measures pure
-             per-stripe execution overhead, not planning).
+  looped   — one stripe at a time: ``BatchedCodecEngine.repair_single`` /
+             ``repair_multi`` at S=1 per stripe, one kernel dispatch each
+             (plan cache warm, so this measures pure per-stripe execution
+             overhead, not planning).
   batched  — ``BatchedCodecEngine``: one compiled plan + one launch for the
              whole batch.
 
@@ -18,7 +19,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core.codec import StripeCodec
 from repro.core.engine import BatchedCodecEngine
 from repro.core.schemes import make_scheme
 
@@ -31,19 +31,17 @@ GEOM = (24, 2, 2)  # the paper's P5
 def _bench_one(name: str, S: int, B: int, rng) -> dict:
     k, r, p = GEOM
     scheme = make_scheme(name, k, r, p)
-    codec = StripeCodec(scheme)
-    engine = BatchedCodecEngine(scheme, backend=codec.backend,
-                                planner=codec.planner)
+    engine = BatchedCodecEngine(scheme)
     data = rng.integers(0, 256, (S, k, B), dtype=np.uint8)
     stripes = np.asarray(engine.encode(data))
 
     failed = 0  # a data block: local-group repair
     batch_avail = {i: stripes[:, i, :] for i in range(scheme.n) if i != failed}
-    per_stripe_avail = [{i: stripes[s, i, :] for i in range(scheme.n)
+    per_stripe_avail = [{i: stripes[s:s + 1, i, :] for i in range(scheme.n)
                          if i != failed} for s in range(S)]
 
     def looped():
-        return [np.asarray(codec.repair_single(failed, a)[0])
+        return [engine.repair_single(failed, a)[0][0]
                 for a in per_stripe_avail]
 
     def batched():
@@ -58,12 +56,12 @@ def _bench_one(name: str, S: int, B: int, rng) -> dict:
     pattern = frozenset({0, k})
     mb_avail = {i: stripes[:, i, :] for i in range(scheme.n)
                 if i not in pattern}
-    ms_avail = [{i: stripes[s, i, :] for i in range(scheme.n)
+    ms_avail = [{i: stripes[s:s + 1, i, :] for i in range(scheme.n)
                  if i not in pattern} for s in range(S)]
 
     def looped2():
-        return [{b: np.asarray(v) for b, v in
-                 codec.repair_multi(pattern, a)[0].items()} for a in ms_avail]
+        return [{b: v[0] for b, v in
+                 engine.repair_multi(pattern, a)[0].items()} for a in ms_avail]
 
     def batched2():
         out, _ = engine.repair_multi(pattern, mb_avail)

@@ -6,7 +6,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.kernels.ops import encode_op
+from repro.kernels.ops import encode_batch_op
 
 from ._util import csv, timed
 
@@ -20,11 +20,11 @@ def run(fast: bool = False) -> dict:
     out = {}
     for (m, k, b) in cases:
         coef = rng.integers(1, 256, (m, k), dtype=np.uint8)
-        data = rng.integers(0, 256, (k, b), dtype=np.uint8)
+        data = rng.integers(0, 256, (1, k, b), dtype=np.uint8)
         for backend in ("ref", "gf", "crs", "mxu"):
             try:
                 _, us = timed(lambda: np.asarray(
-                    encode_op(coef, data, backend=backend)), repeats=2)
+                    encode_batch_op(coef, data, backend=backend)), repeats=2)
                 mbps = k * b / (us / 1e6) / 1e6
                 out[f"{backend}/{m}x{k}x{b}"] = {"us": us, "MBps": mbps}
                 csv(f"kernels/{backend}/{m}x{k}x{b}", us, f"{mbps:.1f}MB/s")

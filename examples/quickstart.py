@@ -5,7 +5,7 @@ PYTHONPATH=src python examples/quickstart.py
 import numpy as np
 
 from repro.core import make_scheme, metrics
-from repro.core.codec import StripeCodec
+from repro.core.engine import BatchedCodecEngine
 from repro.core.repair import multi_repair_plan, single_repair_plan
 
 print("== CP-Azure (24,2,2) vs Azure LRC (24,2,2) ==")
@@ -26,13 +26,13 @@ print("\n== metrics (paper Table III, P5 column) ==")
 for name, s in (("azure", az), ("cp-azure", cp)):
     print(f"{name:9s} ADRC={metrics.adrc(s):6.2f} ARC1={metrics.arc1(s):6.2f}")
 
-print("\n== bytes-level repair through the JAX/Pallas codec ==")
-codec = StripeCodec(make_scheme("cp-azure", 6, 2, 2))
+print("\n== bytes-level repair through the JAX/Pallas engine ==")
+engine = BatchedCodecEngine(make_scheme("cp-azure", 6, 2, 2))
 rng = np.random.default_rng(0)
-data = rng.integers(0, 256, (6, 1024), dtype=np.uint8)
-stripe = np.asarray(codec.encode(data))
+data = rng.integers(0, 256, (1, 6, 1024), dtype=np.uint8)  # one stripe, S=1
+stripe = engine.encode(data)
 lost = {0, 7}  # D1 and L2
-avail = {i: stripe[i] for i in range(codec.scheme.n) if i not in lost}
-rebuilt, plan = codec.repair_multi(lost, avail)
-ok = all((np.asarray(rebuilt[b]) == stripe[b]).all() for b in lost)
-print(f"lost D1+L2 -> read {plan.cost} blocks, bit-exact={ok}")
+avail = {i: stripe[:, i] for i in range(engine.scheme.n) if i not in lost}
+rebuilt, plan = engine.repair_multi(lost, avail)
+ok = all((rebuilt[b] == stripe[:, b]).all() for b in lost)
+print(f"lost D1+L2 -> read {plan.meta.cost} blocks, bit-exact={ok}")

@@ -7,9 +7,9 @@ Layers:
   repair      single-/multi-node repair planning (local-first, cascading)
   metrics     ADRC / ARC1 / ARC2 / locality portions
   reliability Markov-chain MTTDL
-  codec       JAX/Pallas stripe encode-decode data path (per stripe)
   planner     compiled + LRU-cached GF plans per (scheme, pattern, policy)
-  engine      batched multi-stripe executor (one launch per failure pattern)
+  engine      the codec: batched stripe executor (one launch per failure
+              pattern; a single stripe is S=1)
 """
 from .schemes import (  # noqa: F401
     LRCScheme,

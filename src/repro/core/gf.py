@@ -5,8 +5,8 @@ Two tiers:
 * **numpy tier** (planning path): coefficient generation, Gaussian
   elimination / rank / inverse for repair planning and fault-tolerance
   enumeration. Mirrors what the paper's coordinator does in C++/Jerasure.
-* **jnp tier** (data path): vectorized encode/decode used by ``repro.codec``
-  and as the oracle for the Pallas kernels in ``repro.kernels``.
+* **jnp tier** (data path): vectorized encode/decode, the oracle for the
+  Pallas kernels in ``repro.kernels``.
 
 Field: GF(2^8) with the AES/Jerasure-standard primitive polynomial
 ``x^8 + x^4 + x^3 + x^2 + 1`` (0x11D). Addition is XOR; w=8 supports stripes

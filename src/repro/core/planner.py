@@ -123,7 +123,7 @@ class RepairPlanner:
 
     Thread-safe: stripe stores may plan from concurrent repair workers. The
     cache key never includes payload data, so a planner can be shared by any
-    number of codecs/engines over the same scheme.
+    number of engines over the same scheme.
     """
 
     def __init__(self, scheme: LRCScheme, maxsize: int = 512):
@@ -160,18 +160,6 @@ class RepairPlanner:
         return len(self._cache)
 
     # ------------------------------------------------------------ raw solves
-    def coeffs_for(self, target: int, reads: Sequence[int]
-                   ) -> Optional[np.ndarray]:
-        """Cached reconstruction coefficients: gen[reads].T @ x = gen[target]."""
-        reads = tuple(reads)
-        key = ("coeffs", target, reads)
-        try:
-            return self._get(
-                key, lambda: self._solve_many("single", (target,), reads)
-            ).coeffs[0]
-        except _Unsolvable:
-            return None
-
     def _solve_many(self, op: str, targets: Sequence[int],
                     reads: Sequence[int], meta=None) -> CompiledPlan:
         gen = self.scheme.gen

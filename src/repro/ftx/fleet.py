@@ -107,7 +107,7 @@ class FleetRepairReport:
     device_launches: int = 0    # per-device kernel executions, all launches
     # Async-pipeline observability (repro.ftx.pipeline): per-stage wall
     # spans plus how much of them the double buffer hid. Zero on the
-    # synchronous paths except the stage spans, which are accounted there
+    # synchronous path except the stage spans, which are accounted there
     # too (serially, so overlap_seconds stays 0).
     pipelined: bool = False
     windows: int = 0            # pipeline windows executed
@@ -303,9 +303,9 @@ def repair_failed_nodes(store, nodes: Iterable[int], *,
     nodes = tuple(nodes)
     for node in nodes:
         store.fail_node(node)
-    before = store.codec.planner.stats.snapshot()
+    before = store.engine.planner.stats.snapshot()
     tele = store.repair_all(spare_of=spare_of, options=o)
-    after = store.codec.planner.stats.snapshot()
+    after = store.engine.planner.stats.snapshot()
     if revive:
         for node in nodes:
             store.revive_node(node)

@@ -1,8 +1,8 @@
 """Options objects for the repair and serving paths (PR 8 API collapse).
 
-``StripeStore.repair_all`` grew one keyword per PR — ``batched=``,
-``mesh_rules=``, ``pipeline=``, ``window=``, ``pipeline_hook=``,
-``placement=``, ``schedule=`` — and every layer above it
+``StripeStore.repair_all`` grew one keyword per PR — ``mesh_rules=``,
+``pipeline=``, ``window=``, ``pipeline_hook=``, ``placement=``,
+``schedule=`` — and every layer above it
 (``RepairPipeline``, ``repair_failed_nodes``, ``FailureInjector``, the
 benchmarks) re-declared the same sprawl to forward it. This module
 collapses the knobs into two frozen dataclasses:
@@ -34,7 +34,6 @@ class RepairOptions:
     ``schedule`` -> ``cfg.stripe_schedule``, ``placement`` -> the store's
     map, ``mesh_rules`` -> the ambient ``with_rules`` context).
     """
-    batched: bool = True                 # pattern-batched engine vs seed loop
     mesh_rules: Any = None               # device sharding of the stripe axis
     pipeline: Optional[bool] = None      # async double-buffered windows
     window: Optional[int] = None         # stripes per window/launch chunk

@@ -3,15 +3,15 @@
 Mirrors the paper's prototype (§V): a coordinator (this class) holds stripe/
 block/object/node indexes; "data nodes" are directories (one per virtual
 node) holding block files. Encode/decode/repair byte-crunching runs through
-the JAX/Pallas codec; repair *planning* uses the paper's local-first
+one ``BatchedCodecEngine`` (a single stripe is S=1); its ``RepairPlanner``
+plans every repair and degraded read with the paper's local-first
 algorithms, and every operation is bandwidth-accounted (blocks and bytes
 read) so the cloud experiments (Figs 6-9) can be reproduced as simulations
 with a configurable link-speed model.
 
 Also implements the paper's file-level optimization (§V-C): objects packed
 into stripes with byte-offsets, degraded reads fetch only the needed byte
-ranges of surviving blocks; plus straggler-hedged reads (read k+h candidate
-sources, use the first k by simulated node latency).
+ranges of surviving blocks.
 """
 from __future__ import annotations
 
@@ -28,10 +28,8 @@ from typing import Optional
 import numpy as np
 
 from repro import obs
-from repro.core.codec import StripeCodec
 from repro.core.engine import BatchedCodecEngine
-from repro.core.repair import (MultiRepairPlan, multi_repair_plan,
-                               single_repair_plan)
+from repro.core.repair import MultiRepairPlan
 from repro.core.schemes import make_scheme
 from repro.kernels.ops import default_backend as _default_backend
 from repro.serve.telemetry import LatencyRecorder
@@ -78,7 +76,6 @@ class StoreConfig:
     backend: str = dataclasses.field(
         default_factory=lambda: _default_backend(cpu="ref"))
     bandwidth_gbps: float = 1.0        # per-link model for simulated time
-    hedge: int = 0                     # extra sources for hedged reads
     seed: int = 0
     batch_stripes: int = 64            # max stripes per batched repair launch
     pipeline_window: int = 32          # stripes per async-repair window (0 = sync)
@@ -164,7 +161,7 @@ class Telemetry:
     put_seconds: float = 0.0
     # The pipeline coordinator's part of compute_seconds: putting a
     # one-chip window's stack on the device before its launch thread runs
-    # the launch; 0 on the synchronous paths (put_seconds when sharded).
+    # the launch; 0 on the synchronous path (put_seconds when sharded).
     stack_put_seconds: float = 0.0
     # Locality accounting (PlacementMap): reads served from the reading
     # shard's own nodes vs. cross-shard fetches, and how many gather bytes
@@ -258,11 +255,9 @@ class StripeStore:
                              f"{cfg.rebuild_destinations!r} "
                              f"(choose from in_place, topology)")
         self.scheme = make_scheme(cfg.scheme, cfg.k, cfg.r, cfg.p)
-        self.codec = StripeCodec(self.scheme, backend=cfg.backend)
-        # Batched executor sharing the codec's plan cache: fleet repair
-        # issues one launch per (failure pattern, <=batch_stripes chunk).
-        self.engine = BatchedCodecEngine(self.scheme, backend=cfg.backend,
-                                         planner=self.codec.planner)
+        # The one codec: every encode, repair and degraded read is a launch
+        # of this engine, and its planner plans every one of them.
+        self.engine = BatchedCodecEngine(self.scheme, backend=cfg.backend)
         self.root = Path(root)
         self.n = self.scheme.n
         self.num_nodes = num_nodes or self.n
@@ -543,7 +538,7 @@ class StripeStore:
             return
         sid = self._open_sid
         data = self._open_buf.reshape(self.cfg.k, self.cfg.block_size)
-        stripe = np.asarray(self.codec.encode(data))
+        stripe = self.engine.encode(data[None])[0]
         for b in range(self.n):
             self._write_block(sid, b, stripe[b])
         self._open_sid = None
@@ -604,49 +599,17 @@ class StripeStore:
             with self._tele_lock:
                 self.telemetry.cache_hits += 1
             return cached[lo:hi].copy()
-        # degraded read: plan repair for just this block, fetch only [lo, hi)
-        plan = self._pick_single_plan(sid, block, down)
-        if plan is None:                      # plan sources dead -> multi plan
-            mplan = multi_repair_plan(self.scheme, down)
-            if not mplan.feasible:
-                raise IOError(f"stripe {sid}: unrecoverable ({sorted(down)})")
-            rebuilt, _ = self._execute_multi(sid, mplan, down, (lo, hi))
-            return rebuilt[block]
-        reads = sorted(plan.reads)
-        coeffs = self.codec.reconstruction_coeffs(block, reads)
-        chunks = [self._read_block(sid, b, (lo, hi)) for b in reads]
-        import jax.numpy as jnp
-        piece = self.codec.combine(coeffs, [jnp.asarray(c) for c in chunks])
-        return np.asarray(piece)
-
-    def _pick_single_plan(self, sid: int, block: int, down: frozenset[int]):
-        """Pick a single-block repair plan whose sources are all alive.
-
-        With hedging on (straggler mitigation), all structural candidates
-        compete on *simulated completion time* — the critical-path node
-        latency plus the transfer — instead of block count alone; the paper's
-        cascaded group gives CP-LRCs more alternatives to hedge across.
-        """
-        from repro.core.repair import single_repair_candidates
-
-        cands = [c for c in single_repair_candidates(self.scheme, block)
-                 if not (c.reads & down)]
-        if not cands:
-            return None
-        if not self.cfg.hedge:
-            paper = single_repair_plan(self.scheme, block)
-            if not (paper.reads & down):
-                return paper
-            return min(cands, key=lambda c: c.cost)
-        node_of = self.stripes[sid].node_of_block
-
-        def sim_time(c):
-            lat = max(self.latency_ms[node_of[b]] for b in c.reads)
-            return lat / 1e3 + c.cost * self.cfg.block_size * 8 / (
-                self.cfg.bandwidth_gbps * 1e9)
-
-        pool = sorted(cands, key=sim_time)[:1 + self.cfg.hedge]
-        return pool[0]
+        # Degraded read: the serving plan for this block, but fetch only
+        # [lo, hi) of each source (§V-C).
+        try:
+            plan = self.engine.planner.serving_plan(block, down)
+        except RuntimeError:
+            raise IOError(f"stripe {sid}: unrecoverable ({sorted(down)})"
+                          ) from None
+        stack = np.stack([self._read_block(sid, b, (lo, hi))
+                          for b in plan.reads])
+        out = self.engine.execute(plan, stack[None])
+        return out[0, plan.targets.index(block)]
 
     # ------------------------------------------------------------- serving
     def read(self, sid: int, block: int, *,
@@ -891,14 +854,12 @@ class StripeStore:
         (:class:`repro.ftx.options.RepairOptions`); the pre-PR-8 loose
         keyword spellings were removed after their one deprecation cycle.
 
-        ``options.batched=True`` (default) groups affected stripes by failure
-        pattern and repairs each group through the batched engine — one
-        compiled plan and one kernel launch per ``(pattern, chunk)`` of up to
-        ``cfg.batch_stripes`` stripes — instead of one solve + one launch per
-        stripe. ``batched=False`` keeps the seed per-stripe loop (benchmark
-        baseline). Results are bit-identical between the two paths.
+        Affected stripes are grouped by failure pattern and each group
+        repairs through the batched engine — one compiled plan and one
+        kernel launch per ``(pattern, chunk)`` of up to
+        ``cfg.batch_stripes`` stripes.
 
-        ``pipeline`` routes the batched path through the double-buffered
+        ``pipeline`` routes the repair through the double-buffered
         async pipeline (``repro.ftx.pipeline``): pattern chunks split into
         ``cfg.pipeline_window``-stripe windows (``window`` overrides) whose
         disk reads, device launches and write-backs overlap. ``None``
@@ -918,14 +879,14 @@ class StripeStore:
         device span seen) and ``device_launches`` (total per-device kernel
         executions across all launches). ``read/compute/write_seconds``
         report per-stage wall spans; ``overlap_seconds`` is the stage time
-        the pipeline hid (0 on the synchronous paths). ``put_seconds`` is
+        the pipeline hid (0 on the synchronous path). ``put_seconds`` is
         the coordinator's time putting sharded launches' per-shard buffers
         on their devices (0 where no launch is sharded). The pipeline's
         coordinator puts each window's input on the device and hands the
         launch to a launch thread, which dispatches it, waits for it and
         copies it back: ``stack_put_seconds`` is the coordinator's part of
         ``compute_seconds``, its put of one-chip windows (0 on the
-        synchronous paths), and ``deferred_launches`` counts the launches
+        synchronous path), and ``deferred_launches`` counts the launches
         the launch thread ran (re-planned and synchronous launches are not
         counted).
 
@@ -976,8 +937,7 @@ class StripeStore:
         from repro.dist.stripes import stripe_axis_span
 
         o = options if options is not None else RepairOptions()
-        batched, mesh_rules = o.batched, o.mesh_rules
-        pipeline, window = o.pipeline, o.window
+        mesh_rules, pipeline, window = o.mesh_rules, o.pipeline, o.window
         pipeline_hook, placement, schedule = (o.pipeline_hook, o.placement,
                                               o.schedule)
         destinations = o.destinations
@@ -998,8 +958,8 @@ class StripeStore:
             raise ValueError(f"unknown rebuild destinations "
                              f"{destinations!r} "
                              f"(choose from in_place, topology)")
-        use_pipeline = batched and (pipeline if pipeline is not None
-                                    else self.cfg.pipeline_window > 0)
+        use_pipeline = (pipeline if pipeline is not None
+                        else self.cfg.pipeline_window > 0)
         before = self.telemetry.copy()
         t0 = time.perf_counter()
         launches = 0
@@ -1039,7 +999,7 @@ class StripeStore:
                 dest_of = self._destinations(affected)
                 dest_total = len(dest_of)
                 dest_copyset = self._destination_copyset(affected, dest_of)
-            for down, sids in groups if batched else ():
+            for down, sids in groups:
                 try:
                     compiled = self.engine.planner.multi_plan(down)
                 except RuntimeError:
@@ -1059,18 +1019,6 @@ class StripeStore:
                         schedule=schedule))
                 res = PipelineResult()
                 pipe_windows = pipe.windows(work, res)
-        if not batched:
-            for down, sids in groups:
-                for sid in sids:
-                    plan = multi_repair_plan(self.scheme, down)
-                    if not plan.feasible:
-                        raise IOError(f"stripe {sid} unrecoverable: {sorted(down)}")
-                    rebuilt, _ = self._execute_multi(sid, plan, down, None)
-                    self._finish_repair([sid], down, plan,
-                                        {b: v[None] for b, v in rebuilt.items()},
-                                        spare_of, dest_of)
-                    launches += 1
-                    device_launches += 1
         if pipe is not None:
             pipe.run(pipe_windows, res)
             launches += res.launches
@@ -1127,17 +1075,14 @@ class StripeStore:
             "stripes_repaired": sum(len(sids) for sids in affected.values()),
             "patterns": len(affected),
             # The formulation the repair launches actually ran (see
-            # kernels.ops.effective_backend). Batched launches take the
-            # engine's per-launch record; with zero launches (or the
-            # per-stripe path, which never substitutes) this is the
-            # configured backend's static resolution.
-            "effective_backend": ((self.engine.effective_backend
-                                   or _eff(self.cfg.backend))
-                                  if batched else self.cfg.backend),
+            # kernels.ops.effective_backend): the engine's per-launch
+            # record, or with no launch yet the configured backend's static
+            # resolution.
+            "effective_backend": (self.engine.effective_backend
+                                  or _eff(self.cfg.backend)),
             "launches": launches,
             "devices": devices,
             "device_launches": device_launches,
-            "batched": batched,
             "pipelined": bool(use_pipeline and work),
             "windows": windows,
             "replans": replans,
@@ -1162,7 +1107,7 @@ class StripeStore:
                 t.gather_buffer_reuses - before.gather_buffer_reuses,
             "gather_buffer_allocs":
                 t.gather_buffer_allocs - before.gather_buffer_allocs,
-            "schedule": schedule if batched else "none",
+            "schedule": schedule,
             "destinations": destinations,
             "blocks_relocated": t.blocks_relocated - before.blocks_relocated,
             "destination_copyset_fraction":
@@ -1304,15 +1249,6 @@ class StripeStore:
                 self.telemetry.repairs_global += len(sids)
             self.telemetry.blocks_relocated += relocated
 
-    def _execute_multi(self, sid: int, plan, down: frozenset[int],
-                       rng: Optional[tuple[int, int]]):
-        import jax.numpy as jnp
-        avail = {}
-        for b in plan.reads:
-            avail[b] = jnp.asarray(self._read_block(sid, b, rng))
-        rebuilt, _ = self.codec.repair_multi(down, avail)
-        return {b: np.asarray(v) for b, v in rebuilt.items()}, plan
-
     # ---------------------------------------------------------- persistence
     def save_manifest(self) -> None:
         manifest = {
@@ -1335,7 +1271,11 @@ class StripeStore:
 
         root = Path(root)
         manifest = json.loads((root / "manifest.json").read_text())
-        cfg = StoreConfig(**manifest["cfg"])
+        # A manifest written by an older store may hold config fields since
+        # retired; they set nothing now, so only the current fields load.
+        fields = {f.name for f in dataclasses.fields(StoreConfig)}
+        cfg = StoreConfig(**{k: v for k, v in manifest["cfg"].items()
+                             if k in fields})
         topo_doc = manifest.get("topology")
         topology = Topology(**topo_doc) if topo_doc else None
         store = cls(root, cfg, num_nodes=topology.num_nodes if topology
@@ -1386,7 +1326,7 @@ class StripeStreamWriter:
 
     def write_window(self, first: int, encoded: np.ndarray) -> None:
         """Persist ``encoded`` — shape ``(S, n, block_size)``, already
-        through the codec — as stream stripes ``first .. first+S-1``.
+        through the engine — as stream stripes ``first .. first+S-1``.
         Thread-safe; windows may land in any order."""
         enc = np.asarray(encoded, np.uint8)
         n, B = self.store.n, self.store.cfg.block_size

@@ -6,11 +6,10 @@ batch; a single stripe is S=1:
   bitmatrix_encode_batched   — CRS select-and-XOR on packed bit-planes (VPU)
   mod2_matmul_encode_batched — per-bit-plane matmul mod 2 (MXU)
 
-``ops.py`` is the dispatch layer used by ``repro.core.codec``, the batched
-engine and the stripe store.
+``ops.py`` is the dispatch layer used by the batched engine
+(``repro.core.engine``), the one codec of the stripe store.
 """
 from .gf256_matmul import gf256_matmul_batched  # noqa: F401
 from .bitmatrix_encode import (bitmatrix_encode_batched,  # noqa: F401
                                mod2_matmul_encode_batched)
-from .ops import encode_op, gf_matmul_op  # noqa: F401
 from . import ref  # noqa: F401

@@ -11,7 +11,7 @@ divisibility. ``backend``:
   "ref"   — pure-jnp table oracle (no Pallas)
 
 Every backend supports every op — encode, repair/decode combines — on a
-stripe batch; the flat ops are the batched ones at S=1. The bit-plane
+stripe batch; a single stripe is a batch of S=1. The bit-plane
 backends ("crs"/"mxu") run general GF matmuls through the packed
 bit-matrix expansion of the byte coefficient matrix
 (``repro.core.gf.matrix_to_bitmatrix``): callers that hold a compiled plan
@@ -100,16 +100,6 @@ def _as_bitmatrix(coef, bitmatrix) -> jax.Array:
         raise ValueError(f"bitmatrix shape {bm.shape} does not match the "
                          f"{coef.shape} coefficient matrix (want {want})")
     return bm
-
-
-def gf_matmul_op(coef, data, **kwargs) -> jax.Array:
-    """GF(2^8) ``coef (m,k) @ data (k,B) -> (m,B)``: the batched op at S=1.
-
-    Takes every keyword of :func:`gf_matmul_batch_op` (``backend``,
-    ``interpret``, ``force_pallas``, ``bitmatrix``, ...).
-    """
-    data = jnp.asarray(data, jnp.uint8)
-    return gf_matmul_batch_op(coef, data[None], **kwargs)[0]
 
 
 def _gf_batch_kernel(coef, data, *, backend: str, interpret: bool,
@@ -204,13 +194,6 @@ def gf_matmul_batch_op(coef, data, *, backend: str = "gf",
     return sharded_launch(_gf_batch_kernel, coef, data, mesh_rules,
                           backend=backend, interpret=interpret,
                           force_pallas=force_pallas)
-
-
-def encode_op(coding: np.ndarray, blocks, *, backend: str = "gf",
-              interpret: bool | None = None) -> jax.Array:
-    """Stripe parity ``blocks (k, B) -> (m, B)`` on any backend."""
-    return gf_matmul_op(np.asarray(coding, np.uint8), blocks,
-                        backend=backend, interpret=interpret)
 
 
 def encode_batch_op(coding: np.ndarray, blocks, *, backend: str = "gf",

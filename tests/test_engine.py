@@ -1,13 +1,14 @@
-"""Batched engine + planner: bit-exact vs the per-stripe reference path,
-ragged batches, plan-cache behavior, batched kernel lockstep."""
+"""Batched engine + planner: bit-exact vs the numpy reference and vs
+one-stripe (S=1) launches, ragged batches, plan-cache behavior, batched
+kernel lockstep."""
 import numpy as np
 import pytest
 
-from repro.core.codec import StripeCodec
 from repro.core.engine import BatchedCodecEngine
 from repro.core.gf import gf_rank
 from repro.core.planner import RepairPlanner
-from repro.core.schemes import make_scheme
+from repro.core.schemes import SCHEMES as ALL_SCHEMES, make_scheme
+from repro.kernels.ops import default_backend
 
 SCHEMES = ("cp-azure", "cp-uniform", "azure")
 
@@ -21,53 +22,65 @@ def pair(request):
     # default_backend() honors REPRO_BACKEND, so the CI backend-matrix legs
     # (REPRO_BACKEND=crs / =mxu) drive this whole module through the
     # bit-plane backends.
-    from repro.kernels.ops import default_backend
-
     s = make_scheme(request.param, 8, 2, 2)
-    codec = StripeCodec(s, backend=default_backend())
-    engine = BatchedCodecEngine(s, backend=codec.backend, planner=codec.planner)
-    return s, codec, engine
+    return s, BatchedCodecEngine(s, backend=default_backend())
 
 
 # ------------------------------------------------------------------ encode
 @pytest.mark.parametrize("S", [1, 3, 5])  # ragged/odd batch sizes included
 def test_batched_encode_matches_per_stripe(pair, rng, S):
-    s, codec, engine = pair
+    s, engine = pair
     data = rng.integers(0, 256, (S, s.k, 96), dtype=np.uint8)
-    batch = np.asarray(engine.encode(data))
-    loop = np.stack([np.asarray(codec.encode(data[i])) for i in range(S)])
-    assert (batch == loop).all()
+    batch = engine.encode(data)
+    for i in range(S):
+        assert (batch[i] == s.encode(data[i])).all(), i
 
 
+@pytest.mark.parametrize("geom,S,B", [(("cp-azure", 12, 3, 3), 1, 80),
+                                      (("cp-uniform", 12, 3, 3), 1, 80),
+                                      (("cp-azure", 6, 2, 2), 4, 72)])
 @pytest.mark.parametrize("backend", ["gf", "crs", "mxu", "ref"])
-def test_batched_encode_all_backends(backend, rng):
-    s = make_scheme("cp-azure", 6, 2, 2)
+def test_batched_encode_all_backends(geom, S, B, backend, rng):
+    """The engine's stripes, and the backend's Pallas kernel (interpreted)
+    on the same parity matrix, match the numpy ground truth."""
+    from repro.kernels.ops import gf_matmul_batch_op
+
+    s = make_scheme(*geom)
     engine = BatchedCodecEngine(s, backend=backend)
-    data = rng.integers(0, 256, (4, s.k, 72), dtype=np.uint8)
-    batch = np.asarray(engine.encode(data))
-    for i in range(4):
-        assert (batch[i] == s.encode(data[i])).all(), backend
+    data = rng.integers(0, 256, (S, s.k, B), dtype=np.uint8)
+    batch = engine.encode(data)
+    parity = np.asarray(gf_matmul_batch_op(s.parity_matrix(), data,
+                                           backend=backend,
+                                           force_pallas=backend != "ref"))
+    for i in range(S):
+        want = s.encode(data[i])  # numpy planning-tier ground truth
+        assert (batch[i] == want).all(), backend
+        assert (parity[i] == want[s.k:]).all(), backend
 
 
 # ------------------------------------------------------------------ repair
-def test_batched_single_repair_every_block(pair, rng):
-    s, codec, engine = pair
-    S = 4
+@pytest.mark.parametrize("name,k,S", [(n, 6, 1) for n in sorted(ALL_SCHEMES)]
+                         + [(n, 8, 4) for n in SCHEMES])
+def test_batched_single_repair_every_block(name, k, S, rng):
+    """Every block of every scheme rebuilds bit-exactly, and a batch of S
+    stripes equals S one-stripe launches."""
+    s = make_scheme(name, k, 2, 2)
+    engine = BatchedCodecEngine(s, backend=default_backend())
     data = rng.integers(0, 256, (S, s.k, 64), dtype=np.uint8)
-    stripes = np.asarray(engine.encode(data))
+    stripes = engine.encode(data)
     for failed in range(s.n):
         avail = _stack(stripes, [i for i in range(s.n) if i != failed])
         out, plan = engine.repair_single(failed, avail)
-        loop = np.stack([
-            np.asarray(codec.repair_single(
-                failed, {i: stripes[j, i, :] for i in range(s.n)
-                         if i != failed})[0]) for j in range(S)])
-        assert (np.asarray(out) == stripes[:, failed, :]).all(), failed
-        assert (np.asarray(out) == loop).all(), failed
+        assert (out == stripes[:, failed, :]).all(), (failed, plan.meta.method)
+        loop = np.concatenate([
+            engine.repair_single(failed, {b: v[j:j + 1]
+                                          for b, v in avail.items()})[0]
+            for j in range(S)])
+        assert (out == loop).all(), failed
 
 
 def test_batched_multi_repair_cascade(pair, rng):
-    s, codec, engine = pair
+    s, engine = pair
     S = 3
     data = rng.integers(0, 256, (S, s.k, 48), dtype=np.uint8)
     stripes = np.asarray(engine.encode(data))
@@ -83,7 +96,7 @@ def test_batched_multi_repair_cascade(pair, rng):
 
 
 def test_batched_repair_accepts_dense_availability(pair, rng):
-    s, codec, engine = pair
+    s, engine = pair
     data = rng.integers(0, 256, (2, s.k, 32), dtype=np.uint8)
     stripes = np.asarray(engine.encode(data))
     out, _ = engine.repair_single(1, stripes)  # (S, n, B) array form
@@ -91,7 +104,7 @@ def test_batched_repair_accepts_dense_availability(pair, rng):
 
 
 def test_batched_repair_missing_read_raises(pair, rng):
-    s, codec, engine = pair
+    s, engine = pair
     data = rng.integers(0, 256, (2, s.k, 32), dtype=np.uint8)
     stripes = np.asarray(engine.encode(data))
     plan = engine.planner.single_plan(0)
@@ -102,21 +115,23 @@ def test_batched_repair_missing_read_raises(pair, rng):
 
 
 # ------------------------------------------------------------------ decode
-def test_batched_decode_any_rank_k_subset(pair, rng):
-    s, codec, engine = pair
-    S = 3
+@pytest.mark.parametrize("name,k,S", [("cp-uniform", 6, 1)]
+                         + [(n, 8, 3) for n in SCHEMES])
+def test_batched_decode_any_rank_k_subset(name, k, S, rng):
+    s = make_scheme(name, k, 2, 2)
+    engine = BatchedCodecEngine(s, backend=default_backend())
     data = rng.integers(0, 256, (S, s.k, 40), dtype=np.uint8)
-    stripes = np.asarray(engine.encode(data))
+    stripes = engine.encode(data)
     hits = 0
-    for _ in range(8):
+    for _ in range(10):
         ids = sorted(rng.choice(s.n, s.k, replace=False).tolist())
         if gf_rank(s.gen[ids]) < s.k:
             continue
         hits += 1
-        dec = np.asarray(engine.decode(_stack(stripes, ids)))
+        dec = engine.decode(_stack(stripes, ids))
         assert (dec == data).all()
-        loop = np.stack([np.asarray(codec.decode_all(
-            {i: stripes[j, i, :] for i in ids})) for j in range(S)])
+        loop = np.concatenate([engine.decode(
+            {i: stripes[j:j + 1, i, :] for i in ids}) for j in range(S)])
         assert (dec == loop).all()
     assert hits > 0
 
@@ -148,16 +163,28 @@ def test_plan_cache_lru_eviction():
     assert planner.stats.misses == 4  # recompiled after eviction
 
 
-def test_planner_shared_between_codec_and_engine(rng):
-    s = make_scheme("cp-uniform", 6, 2, 2)
-    codec = StripeCodec(s)
-    engine = BatchedCodecEngine(s, backend="gf", planner=codec.planner)
-    data = rng.integers(0, 256, (2, s.k, 24), dtype=np.uint8)
-    stripes = np.asarray(engine.encode(data))
-    engine.repair_single(0, stripes)
-    baseline = codec.planner.stats.misses
-    codec.repair_single(0, {i: stripes[0, i, :] for i in range(1, s.n)})
-    assert codec.planner.stats.misses == baseline  # codec reused engine's plan
+def test_one_planner_serves_get_and_read(tmp_path, rng):
+    """The object read ``get`` and the serving read ``read`` plan a lost
+    block through the same ``RepairPlanner.serving_plan``: a degraded
+    ``get`` compiles the plan, and ``read`` of the same block (no hot
+    cache) finds it cached: one miss between them."""
+    from repro.ftx import StoreConfig, StripeStore
+
+    cfg = StoreConfig(scheme="cp-uniform", k=6, r=2, p=2, block_size=512,
+                      read_cache_blocks=0)
+    store = StripeStore(tmp_path / "s", cfg)
+    payload = rng.integers(0, 256, 300, dtype=np.uint8)
+    store.put("o", payload.tobytes())
+    store.seal()
+    meta = store.objects["o"]
+    store.fail_node(store.stripes[meta.sid].node_of_block[meta.block])
+    before = store.engine.planner.stats.snapshot()
+    assert (store.get("o") == payload).all()
+    got = store.read(meta.sid, meta.block)
+    after = store.engine.planner.stats.snapshot()
+    assert (got[meta.offset:meta.offset + payload.size] == payload).all()
+    assert after["misses"] - before["misses"] == 1
+    assert after["hits"] - before["hits"] == 1
 
 
 def test_infeasible_pattern_raises():
@@ -269,42 +296,34 @@ def test_execute_on_a_put_stack_matches_the_host_stack(backend, rng,
 
 # -------------------------------------------------------- store integration
 def test_store_batched_repair_bit_identical_and_ragged(tmp_path, rng):
-    """Fleet repair through the store: batched and looped paths agree on
-    disk contents; batch_stripes=2 forces ragged last chunks."""
-    from repro.ftx import (RepairOptions, StoreConfig, StripeStore,
-                           repair_failed_nodes)
+    """Fleet repair through the store rewrites every lost block with the
+    bytes it held before the failure; batch_stripes=2 forces ragged last
+    chunks."""
+    from repro.ftx import StoreConfig, StripeStore, repair_failed_nodes
 
-    def build(root):
-        cfg = StoreConfig(scheme="cp-azure", k=6, r=2, p=2, block_size=1024,
-                          batch_stripes=2)
-        store = StripeStore(root, cfg)
-        r = np.random.default_rng(7)
-        for i in range(5):
-            store.put(f"o{i}", r.integers(0, 256, 5000, dtype=np.uint8).tobytes())
-        store.seal()
-        return store
+    cfg = StoreConfig(scheme="cp-azure", k=6, r=2, p=2, block_size=1024,
+                      batch_stripes=2)
+    store = StripeStore(tmp_path / "a", cfg)
+    r = np.random.default_rng(7)
+    for i in range(5):
+        store.put(f"o{i}", r.integers(0, 256, 5000, dtype=np.uint8).tobytes())
+    store.seal()
+    truth = {(sid, b): store._block_path(sid, b).read_bytes()
+             for sid in store.stripes for b in range(store.scheme.n)}
+    node = store.stripes[0].node_of_block[0]
+    lost = [k for k in truth if store.stripes[k[0]].node_of_block[k[1]] == node]
+    for sid, b in lost:
+        store._block_path(sid, b).unlink()
 
-    sa, sb = build(tmp_path / "a"), build(tmp_path / "b")
-    node = sa.stripes[0].node_of_block[0]
-
-    rep = repair_failed_nodes(sa, [node], options=RepairOptions(batched=True))
-    assert rep.stripes_repaired > 0
+    rep = repair_failed_nodes(store, [node])
+    assert rep.stripes_repaired == len(lost) > 0
     assert rep.plan_cache["misses"] >= 1
-
-    sb.fail_node(node)
-    sb.repair_all(options=RepairOptions(batched=False))
-    sb.revive_node(node)
-
-    for sid in sa.stripes:
-        for b in range(sa.scheme.n):
-            pa = sa._block_path(sid, b)
-            pb = sb._block_path(sid, b)
-            assert pa.read_bytes() == pb.read_bytes(), (sid, b)
+    assert {k: store._block_path(*k).read_bytes() for k in truth} == truth
 
 
 def test_store_unrecoverable_raises_ioerror_both_paths(tmp_path):
-    """Batched and looped repair_all share the IOError contract on an
-    unrecoverable stripe (batched must not leak planner RuntimeErrors)."""
+    """Pipelined and synchronous repair_all share the IOError contract on
+    an unrecoverable stripe (neither leaks planner RuntimeErrors)."""
     from repro.ftx import RepairOptions, StoreConfig, StripeStore
 
     cfg = StoreConfig(scheme="cp-azure", k=6, r=2, p=2, block_size=512)
@@ -314,6 +333,6 @@ def test_store_unrecoverable_raises_ioerror_both_paths(tmp_path):
     # Down 5 blocks of stripe 0: beyond p+r, never decodable.
     for b in range(5):
         store.fail_node(store.stripes[0].node_of_block[b])
-    for batched in (True, False):
+    for pipeline in (True, False):
         with pytest.raises(IOError):
-            store.repair_all(options=RepairOptions(batched=batched))
+            store.repair_all(options=RepairOptions(pipeline=pipeline))

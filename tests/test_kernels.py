@@ -9,7 +9,7 @@ from repro.kernels import ref as R
 from repro.kernels.bitmatrix_encode import (bitmatrix_encode_batched,
                                            mod2_matmul_encode_batched)
 from repro.kernels.gf256_matmul import gf256_matmul_batched
-from repro.kernels.ops import gf_matmul_op
+from repro.kernels.ops import gf_matmul_batch_op
 
 SHAPES = [(2, 4, 128), (4, 6, 256), (8, 24, 512), (9, 96, 128), (3, 17, 384)]
 
@@ -19,7 +19,8 @@ def test_gf256_matmul_kernel(m, k, b, rng):
     coef = rng.integers(0, 256, (m, k), dtype=np.uint8)
     data = rng.integers(0, 256, (k, b), dtype=np.uint8)
     want = gf_matmul(coef, data)
-    got = np.asarray(gf_matmul_op(coef, data, backend="gf", force_pallas=True))
+    got = np.asarray(gf_matmul_batch_op(coef, data[None], backend="gf",
+                                        force_pallas=True)[0])
     assert (got == want).all()
 
 
@@ -40,8 +41,8 @@ def test_bitmatrix_kernels(m, k, b, backend, rng):
     coef = rng.integers(0, 256, (m, k), dtype=np.uint8)
     data = rng.integers(0, 256, (k, b), dtype=np.uint8)
     want = gf_matmul(coef, data)
-    got = np.asarray(gf_matmul_op(coef, data, backend=backend,
-                                  force_pallas=True))
+    got = np.asarray(gf_matmul_batch_op(coef, data[None], backend=backend,
+                                        force_pallas=True)[0])
     assert (got == want).all(), backend
 
 
@@ -57,8 +58,9 @@ def test_property_all_backends_agree(m, k, nwords, seed):
     data = rng.integers(0, 256, (k, b), dtype=np.uint8)
     want = gf_matmul(coef, data)
     for backend in ("gf", "crs", "mxu", "ref"):
-        got = np.asarray(gf_matmul_op(coef, data, backend=backend,
-                                      force_pallas=backend != "ref"))
+        got = np.asarray(gf_matmul_batch_op(
+            coef, data[None], backend=backend,
+            force_pallas=backend != "ref")[0])
         assert (got == want).all(), backend
 
 

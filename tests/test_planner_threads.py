@@ -1,6 +1,6 @@
 """RepairPlanner LRU cache under concurrency.
 
-The planner is shared by the codec, the batched engine and every pipeline
+The planner is shared by the store's engine and every pipeline
 reader/writer thread of a store — and fleet repair may run from multiple
 coordinator threads at once. The cache contract under that load: counters
 stay consistent (hits + misses == lookups, evictions never exceed
@@ -42,7 +42,7 @@ def test_concurrent_repair_all_shares_planner_consistently(tmp_path):
     patterns = {store._down_blocks(sid) for sid in store.stripes
                 if store._down_blocks(sid)}
     assert patterns
-    store.codec.planner.cache_clear()
+    store.engine.planner.cache_clear()
 
     barrier = threading.Barrier(4)
 
@@ -60,7 +60,7 @@ def test_concurrent_repair_all_shares_planner_consistently(tmp_path):
             for sid in store.stripes
             for b in range(store.scheme.n)} == truth
 
-    stats = store.codec.planner.stats
+    stats = store.engine.planner.stats
     assert stats.lookups == stats.hits + stats.misses
     # duplicate concurrent builds are allowed (solve runs outside the
     # lock), but nothing may be lost: every pattern is now a pure hit
@@ -114,7 +114,7 @@ def test_repair_all_concurrent_with_degraded_reads(tmp_path):
     # every degraded read either hit the hot cache or was counted a miss
     # (coalesced waiters are misses too — they paid for the shared decode)
     assert t.cache_hits + t.cache_misses == t.degraded_reads
-    stats = store.codec.planner.stats
+    stats = store.engine.planner.stats
     assert stats.lookups == stats.hits + stats.misses
     # post-repair, post-revive: the written-back blocks serve direct and
     # bit-identical — no reconstruction artifacts survived the race
